@@ -43,4 +43,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

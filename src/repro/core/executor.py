@@ -914,6 +914,13 @@ class CompiledSplitExecutor:
         in-flight dispatch seam."""
         return self._batch_fn(mode)(jnp.asarray(xs, jnp.float32))
 
+    def lower_batch(self, xs, mode: str = "float"):
+        """The lowered :meth:`run_batch` program for ``xs`` — an array or a
+        ``jax.ShapeDtypeStruct`` (which may carry a sharding on a described,
+        unattached device).  ``.as_text()`` shows which kernels the program
+        calls; ``.compile()`` runs the backend's compiler on it."""
+        return self._batch_fn(mode).lower(xs)
+
     def warmup(self, input_shape=None, batch: int | None = None,
                mode: str = "float") -> None:
         """Force compilation ahead of serving (zeros input)."""

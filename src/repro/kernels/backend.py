@@ -2,32 +2,18 @@
 
 The kernels target TPU; everywhere else they must run in Pallas interpret
 mode (the kernel body traced as plain jax ops) so CPU CI and laptops still
-work.  Historically the kernels hardcoded ``interpret=True``, which silently
-kept TPUs on the slow path — callers now pass ``interpret=None`` ("auto")
-and we resolve it here from the actual jax backend.
-
-Override order: explicit argument > ``REPRO_PALLAS_INTERPRET`` env var
-("0"/"1") > auto-detection.
+work.  Callers pass ``interpret=None`` ("auto") and it resolves here from the
+platform alone; an explicit ``interpret=True`` is for tests on a TPU-less
+host.
 """
 from __future__ import annotations
 
-import os
-
 import jax
-
-_PALLAS_NATIVE_BACKENDS = ("tpu",)
-
-
-def default_interpret() -> bool:
-    """True when the Pallas kernels must run in interpret mode (no TPU)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None and env != "":
-        return env not in ("0", "false", "False")
-    return jax.default_backend() not in _PALLAS_NATIVE_BACKENDS
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """Resolve an ``interpret`` kwarg: None means auto-detect."""
+    """Resolve an ``interpret`` kwarg: None means interpret everywhere but
+    on a TPU."""
     if interpret is None:
-        return default_interpret()
+        return jax.default_backend() != "tpu"
     return bool(interpret)

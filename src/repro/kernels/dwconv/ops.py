@@ -23,23 +23,6 @@ def dwconv(x_q, w, scale, bias, *, stride: int = 1, activation=None,
     return out[:c]
 
 
-def dwconv_window(x_win, w, scale, bias, *, stride: int = 1, activation=None,
-                  out_scale=None, block_c: int = 8, interpret: bool | None = None):
-    """3x3 depthwise conv over an explicitly prepared row window (spatial
-    band + halo/zero rows already in place, width padded by 1): pads channels
-    to the block multiple and runs the kernel VALID over the rows as given.
-    ``x_win``: (C, R, W+2) with R = (out_rows-1)*stride + 3."""
-    c = x_win.shape[0]
-    pad_c = (-c) % block_c
-    xp = jnp.pad(x_win, ((0, pad_c), (0, 0), (0, 0)))
-    wp = jnp.pad(w, ((0, pad_c), (0, 0), (0, 0)))
-    sp = jnp.pad(scale, (0, pad_c))
-    bp = jnp.pad(bias, (0, pad_c))
-    out = dwconv3x3(xp, wp, sp, bp, stride=stride, activation=activation,
-                    out_scale=out_scale, block_c=block_c, interpret=interpret)
-    return out[:c]
-
-
 def dwconv_bands(x_win, w, scale, bias, *, stride: int = 1, activation=None,
                  out_scale=None, block_c: int = 8,
                  interpret: bool | None = None):

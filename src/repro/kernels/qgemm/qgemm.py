@@ -41,11 +41,11 @@ def _qgemm_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, acc_ref,
             # b_q added in exact int32; float steps are multiplies only so
             # the result is bit-identical to the executors' jnp epilogue
             # (no FMA-contraction sensitivity — see core.quantize).
-            acc = acc_ref[...] + bias_ref[...][None, :]
-            y = acc.astype(jnp.float32) * scale_ref[...][None, :]
+            acc = acc_ref[...] + bias_ref[...]
+            y = acc.astype(jnp.float32) * scale_ref[...]
         else:
             acc = acc_ref[...].astype(jnp.float32)
-            y = acc * scale_ref[...][None, :] + bias_ref[...][None, :]
+            y = acc * scale_ref[...] + bias_ref[...]
         if activation == "relu":
             y = jnp.maximum(y, 0.0)
         elif activation == "relu6":
@@ -82,7 +82,8 @@ def qgemm(x_q, w_q, scale, bias, *, activation: str | None = None,
     assert k == k2 and m % block_m == 0 and n % block_n == 0 and k % block_k == 0
     n_k = k // block_k
     out_dtype = jnp.int8 if out_scale is not None else jnp.float32
-    int_bias = jnp.issubdtype(jnp.asarray(bias).dtype, jnp.integer)
+    bias = jnp.asarray(bias)
+    int_bias = jnp.issubdtype(bias.dtype, jnp.integer)
     kernel = functools.partial(_qgemm_kernel, n_k=n_k, activation=activation,
                                out_scale=out_scale, int_bias=int_bias)
     return pl.pallas_call(
@@ -91,11 +92,13 @@ def qgemm(x_q, w_q, scale, bias, *, activation: str | None = None,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((block_n,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((block_n,), lambda i, j, kk: (j,)),
+            # per-channel rows: a rank-1 block must match XLA's tiling of
+            # the whole vector, which Mosaic refuses once N > block_n
+            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         interpret=interpret,
-    )(x_q, w_q, scale, bias)
+    )(x_q, w_q, scale.reshape(1, n), bias.reshape(1, n))

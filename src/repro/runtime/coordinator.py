@@ -201,6 +201,11 @@ class Coordinator:
         env = dict(os.environ)
         env["PYTHONPATH"] = src + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+        # A process worker stands in for an MCU, not for a chip.  On a TPU
+        # host the parent already holds the chip (a chip belongs to one
+        # process), so a child that reached for it would fail or hang:
+        # pin the workers to the CPU explicitly.
+        env["JAX_PLATFORMS"] = "cpu"
         h = self.handles[w]
         if self.log_dir:
             os.makedirs(self.log_dir, exist_ok=True)

@@ -146,7 +146,12 @@ class TestBatching:
                        for _ in range(4)])
         bf = ex.run_batch(xs)
         sf = np.stack([ex.run(xs[i]) for i in range(4)])
-        np.testing.assert_allclose(bf, sf, rtol=1e-5, atol=1e-6)
+        # The vmapped float conv reduces in a different order than the
+        # single-sample one.  Float32 reordering error scales with the size
+        # of the summed terms, not with each output, so a logit near zero
+        # can differ by ~1e-6 absolute: bound it at rtol of the output scale.
+        np.testing.assert_allclose(bf, sf, rtol=1e-5,
+                                   atol=1e-5 * float(np.abs(sf).max()))
 
     def test_replicated_input_rows_identical(self, rng):
         """run_batch(stack([x]*B)) must produce B identical rows equal to

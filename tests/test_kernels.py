@@ -4,7 +4,8 @@ import numpy as np
 import pytest
 
 from repro.kernels.decode_attn.ops import flash_decode, flash_decode_ref
-from repro.kernels.dwconv.ops import dwconv, dwconv_ref
+from repro.kernels.dwconv.ops import dwconv, dwconv_bands, dwconv_ref
+from repro.kernels.dwconv.ref import dwconv3x3_ref
 from repro.kernels.qgemm.ops import (qconv2d, qconv2d_ref, qgemm_padded)
 from repro.kernels.qgemm.ref import qgemm_ref
 
@@ -90,6 +91,36 @@ class TestDWConv:
         assert got.shape == exp.shape
         assert np.max(np.abs(np.asarray(got, np.int32)
                              - np.asarray(exp, np.int32))) <= 1
+
+    @pytest.mark.parametrize("c,hw", [(8, 16), (24, 13), (32, 7)])
+    @pytest.mark.parametrize("stride", [1, 2])
+    def test_int_bias_bit_exact(self, rng, c, hw, stride):
+        """The executors' contract: int32 bias + multiply-only epilogue
+        equals the oracle exactly, odd maps and stride-2 phases included."""
+        x = rng.integers(-127, 128, (c, hw, hw)).astype(np.int8)
+        w = rng.integers(-127, 128, (c, 3, 3)).astype(np.int8)
+        s = rng.uniform(1e-4, 1e-3, c).astype(np.float32)
+        b = rng.integers(-5000, 5000, c).astype(np.int32)
+        got = dwconv(x, w, s, b, stride=stride, activation="relu6",
+                     out_scale=0.05)
+        exp = dwconv_ref(x, w, s, b, stride=stride, activation="relu6",
+                         out_scale=0.05)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+
+    @pytest.mark.parametrize("stride", [1, 2])
+    def test_bands_bit_exact(self, rng, stride):
+        """A band stack (bands on the grid) equals each band alone."""
+        bands, c, rows, cols = 3, 19, 2 * 3 + 3, 12
+        x = rng.integers(-127, 128, (bands, c, rows, cols)).astype(np.int8)
+        w = rng.integers(-127, 128, (c, 3, 3)).astype(np.int8)
+        s = rng.uniform(1e-4, 1e-3, c).astype(np.float32)
+        b = rng.integers(-5000, 5000, c).astype(np.int32)
+        got = np.asarray(dwconv_bands(x, w, s, b, stride=stride,
+                                      activation="relu6", out_scale=0.05))
+        for i in range(bands):
+            exp = dwconv3x3_ref(x[i], w, s, b, stride=stride,
+                                activation="relu6", out_scale=0.05)
+            np.testing.assert_array_equal(got[i], np.asarray(exp))
 
     def test_float_out(self, rng):
         x = rng.integers(-127, 128, (8, 10, 10)).astype(np.int8)
