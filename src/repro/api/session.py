@@ -18,7 +18,9 @@ import dataclasses
 import threading
 import time
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.executor import CompiledSplitExecutor, reference_forward
 from ..core.quantize import QuantizedModel, calibrate_scales, quantize_model
@@ -106,13 +108,11 @@ class SessionStats:
     # the seconds/inference the planner predicts pipelining saves vs serial
     transport: str = "serial"
     predicted_overlap_saved_s: float = 0.0
-    # rolling dispatch-latency percentiles over the last _ROLLING_WINDOW
+    # rolling dispatch-latency medians over the last _ROLLING_WINDOW
     # dispatches (NaN before the first): overall and per bucket size —
     # the service-time estimates admission control predicts queueing with
     latency_p50_s: float = float("nan")
-    latency_p99_s: float = float("nan")
     per_bucket_p50_s: dict[int, float] = dataclasses.field(default_factory=dict)
-    per_bucket_p99_s: dict[int, float] = dataclasses.field(default_factory=dict)
     # plan-search telemetry carried over from the Plan this session serves
     # (core.search.SearchStats; zeros/NaN when serving a bare SplitPlan or
     # a plan deserialized from a pre-search-stats payload)
@@ -132,15 +132,23 @@ class Ticket:
     ticket is still unfulfilled after ``timeout`` seconds, and re-raises the
     dispatch exception if the batch this request rode in failed: a raising
     dispatch rejects its tickets instead of stranding them.
+
+    The request's stamps (``queued_at``, ``dispatched_at``, ``completed_at``)
+    share the ``time.perf_counter()`` clock, and ``batch`` names the dispatch
+    it rode in: the ``batch`` metadata of that dispatch's profiler spans.
     """
 
-    __slots__ = ("_session", "_value", "_error", "_event", "_t_done")
+    __slots__ = ("_session", "_value", "_error", "_event", "_t_queued",
+                 "_t_dispatched", "_batch", "_t_done")
 
     def __init__(self, session: "Session | None" = None):
         self._session = session
         self._value = None
         self._error: BaseException | None = None
         self._event = threading.Event()
+        self._t_queued = float("nan")
+        self._t_dispatched = float("nan")
+        self._batch: int | None = None
         self._t_done = float("nan")
 
     def done(self) -> bool:
@@ -152,6 +160,23 @@ class Ticket:
         pending) — lets a load generator compute end-to-end latency without
         racing to observe the event itself."""
         return self._t_done
+
+    @property
+    def queued_at(self) -> float:
+        """``time.perf_counter()`` stamp of the request entering its queue
+        (NaN until then)."""
+        return self._t_queued
+
+    @property
+    def dispatched_at(self) -> float:
+        """``time.perf_counter()`` stamp of the request being taken into a
+        micro-batch, one stamp for the whole batch (NaN until then)."""
+        return self._t_dispatched
+
+    @property
+    def batch(self) -> int | None:
+        """Id of the dispatch this request rode in (None until taken)."""
+        return self._batch
 
     def result(self, timeout: float | None = None) -> np.ndarray:
         if not self._event.is_set() and self._session is not None:
@@ -165,6 +190,13 @@ class Ticket:
     def exception(self) -> BaseException | None:
         """The dispatch error that rejected this ticket (None if none/undone)."""
         return self._error
+
+    def _queued(self) -> None:
+        self._t_queued = time.perf_counter()
+
+    def _taken(self, batch: int, t: float) -> None:
+        self._batch = batch
+        self._t_dispatched = t
 
     def _fulfill(self, value: np.ndarray) -> None:
         self._value = value
@@ -189,17 +221,22 @@ class InflightDispatch:
     ``wait()`` forces the result, records the dispatch into the owning
     session's stats (wall time measured enqueue -> ready, so under pipelining
     it includes device queueing — the effective per-batch service time), and
-    returns the unpadded outputs.
+    returns the unpadded outputs.  The wait is two profiler spans: the host
+    waiting for the device (``session.ready``), then the device-to-host copy
+    (``session.fetch``).
     """
 
-    __slots__ = ("_session", "_n", "_bucket", "_out", "_t0", "_result")
+    __slots__ = ("_session", "_n", "_bucket", "_out", "_t0", "_batch",
+                 "_result")
 
-    def __init__(self, session: "Session", n: int, bucket: int, out, t0: float):
+    def __init__(self, session: "Session", n: int, bucket: int, out, t0: float,
+                 batch: int):
         self._session = session
         self._n = n
         self._bucket = bucket
         self._out = out
         self._t0 = t0
+        self._batch = batch
         self._result: np.ndarray | None = None
 
     @property
@@ -210,13 +247,21 @@ class InflightDispatch:
     def bucket(self) -> int:
         return self._bucket
 
+    @property
+    def batch(self) -> int:
+        """The dispatch's id (its spans' ``batch`` metadata)."""
+        return self._batch
+
     def wait(self) -> np.ndarray:
         if self._result is None:
-            out = np.asarray(self._out)     # blocks until the device is done
-            dt = time.perf_counter() - self._t0
+            with TraceAnnotation("session.ready", batch=self._batch):
+                jax.block_until_ready(self._out)
+            with TraceAnnotation("session.fetch", batch=self._batch):
+                out = np.asarray(self._out)
+                dt = time.perf_counter() - self._t0
+                self._result = out[:self._n]
             self._out = None
             self._session._record_dispatch(self._n, self._bucket, dt)
-            self._result = out[:self._n]
         return self._result
 
 
@@ -271,6 +316,9 @@ class Session:
         self._wall_s = 0.0
         self._per_bucket: dict[int, int] = {}
         self._rolling = RollingLatency()
+        # id of the next dispatch; a Server hosting this session sets it to
+        # its own dispatch sequence, so ids are unique across its tenants
+        self._next_batch = 0
 
     # -- calibration ---------------------------------------------------------
     def _calibrate(self, calibration, n_samples: int, seed: int) -> QuantizedModel:
@@ -325,23 +373,36 @@ class Session:
         scheduler can keep a bucket in flight on the device while it forms
         the next micro-batch from whatever has queued — no flush barrier.
         Stats are recorded when the returned handle's ``wait()`` forces.
+        The padding and the enqueue are the profiler span
+        ``session.dispatch``.
         """
         n = len(xs)
         if not 1 <= n <= self.max_batch:
             raise ValueError(f"dispatch of {n} requests (want 1..{self.max_batch})")
-        b = self.bucket_for(n)
-        if b > n:
-            pad = np.zeros((b - n, *xs.shape[1:]), np.float32)
-            batch = np.concatenate([xs, pad])
-        else:
-            batch = xs
-        t0 = time.perf_counter()
-        out = self.engine.run_batch_async(batch, mode=self._mode)
-        return InflightDispatch(self, n, b, out, t0)
+        seq = self._next_batch
+        self._next_batch = seq + 1
+        with TraceAnnotation("session.dispatch", batch=seq):
+            b = self.bucket_for(n)
+            if b > n:
+                pad = np.zeros((b - n, *xs.shape[1:]), np.float32)
+                batch = np.concatenate([xs, pad])
+            else:
+                batch = xs
+            t0 = time.perf_counter()
+            out = self.engine.run_batch_async(batch, mode=self._mode)
+        return InflightDispatch(self, n, b, out, t0, seq)
 
-    def _dispatch(self, xs: np.ndarray) -> np.ndarray:
-        """One padded engine dispatch for n <= max bucket requests."""
-        return self.dispatch_async(xs).wait()
+    def _serve(self, xs: np.ndarray, tickets=()) -> np.ndarray:
+        """Dispatch ``xs`` in ``max_batch`` chunks, one at a time; each
+        chunk's ``tickets`` are stamped as taken into its dispatch."""
+        outs = []
+        for i in range(0, len(xs), self.max_batch):
+            t_taken = time.perf_counter()
+            disp = self.dispatch_async(xs[i:i + self.max_batch])
+            for t in tickets[i:i + self.max_batch]:
+                t._taken(disp.batch, t_taken)
+            outs.append(disp.wait())
+        return np.concatenate(outs)
 
     def submit_many(self, xs) -> np.ndarray:
         """Serve a bulk of requests, micro-batched into padded buckets.
@@ -354,8 +415,7 @@ class Session:
         if len(xs) == 0:
             dtype = np.int8 if self._mode == "int8" else np.float32
             return np.zeros((0, *self.model.out_shape), dtype)
-        return np.concatenate([self._dispatch(xs[i:i + self.max_batch])
-                               for i in range(0, len(xs), self.max_batch)])
+        return self._serve(xs)
 
     def run(self, x) -> np.ndarray:
         """Serve one request now (bucket-1 compiled path)."""
@@ -366,6 +426,7 @@ class Session:
         :class:`Ticket` whose ``result()`` flushes on demand."""
         t = Ticket(self)
         self._pending.append((self.check_input(x), t))
+        t._queued()
         return t
 
     def flush(self) -> int:
@@ -382,7 +443,8 @@ class Session:
             return 0
         pending, self._pending = self._pending, []
         try:
-            ys = self.submit_many(np.stack([x for x, _ in pending]))
+            ys = self._serve(np.stack([x for x, _ in pending]),
+                             [t for _, t in pending])
         except Exception as e:
             for _, ticket in pending:
                 ticket._reject(e)
@@ -478,10 +540,7 @@ class Session:
             predicted_overlap_saved_s=(self.plan.overlap_saved_s
                                        if self.plan is not None else 0.0),
             latency_p50_s=self._rolling.percentile(50),
-            latency_p99_s=self._rolling.percentile(99),
             per_bucket_p50_s={b: self._rolling.percentile(50, key=b)
-                              for b in self._rolling.keys()},
-            per_bucket_p99_s={b: self._rolling.percentile(99, key=b)
                               for b in self._rolling.keys()},
             search_candidates_evaluated=(search_stats or {}).get(
                 "candidates_evaluated", 0),

@@ -33,6 +33,15 @@ Failure isolation: a dispatch that raises rejects exactly the tickets that
 rode in it (their ``result()`` re-raises) and the scheduler keeps serving —
 one tenant's poisoned batch cannot take the server down.
 
+Tracing: the scheduler thread's work is ``jax.profiler.TraceAnnotation``
+spans — ``serve.idle`` (nothing queued, nothing in flight),
+``serve.form`` (EDF selection, take, stacking the inputs),
+``session.dispatch``, ``session.ready`` and ``session.fetch`` (inside
+:class:`Session`), ``serve.fulfill`` (answering or rejecting the tickets).
+A dispatch's spans carry ``batch=<id>``, the Server's dispatch sequence
+number, which each of its tickets carries as ``Ticket.batch``.  With no
+profiler running a span is an inactive TraceMe.
+
 Synchronous by design: clients are threads calling ``submit()`` and
 blocking on tickets.  The asyncio distributed runtime (``repro.runtime``)
 stays a per-plan execution backend underneath a ``Session``; this scheduler
@@ -45,6 +54,7 @@ import threading
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..api.plan import Plan
 from ..api.session import Session, Ticket
@@ -97,6 +107,7 @@ class Server:
         self._running = False
         self._draining = False
         self._inflight_batches = 0
+        self._batches_formed = 0     # the next dispatch's id
         self._thread: threading.Thread | None = None
 
     # -- tenancy -------------------------------------------------------------
@@ -207,6 +218,7 @@ class Server:
                 max_batch=t.session.max_batch)
             req = make_request(x, tenant, self._clock(), t.slo)
             t.queue.append(req)
+            req.ticket._queued()
             self._work.notify()
         return req.ticket
 
@@ -238,7 +250,8 @@ class Server:
         return any(t.queue for t in self._tenants.values())
 
     def _form_batch(self, full_only: bool = False):
-        """Under the lock: pick a tenant (EDF) and take its next micro-batch.
+        """Under the lock: pick a tenant (EDF) and take its next micro-batch,
+        stamping its tickets with the dispatch's id and ``dispatched_at``.
 
         ``full_only`` restricts candidates to tenants with a full
         ``max_batch`` queued — the scheduler's bucket-filling rule: partial
@@ -254,6 +267,11 @@ class Server:
         t = self._tenants[name]
         reqs = self.batcher.take(t.queue, t.session.max_batch)
         self._inflight_batches += 1
+        seq = self._batches_formed
+        self._batches_formed = seq + 1
+        now = time.perf_counter()
+        for r in reqs:
+            r.ticket._taken(seq, now)
         return t, reqs
 
     def _loop(self) -> None:
@@ -262,7 +280,8 @@ class Server:
             batch = None
             with self._lock:
                 while self._running and not self._has_queued() and not inflight:
-                    self._work.wait(0.1)
+                    with TraceAnnotation("serve.idle"):
+                        self._work.wait(0.1)
                 if not self._has_queued() and not inflight:
                     if not self._running:
                         break
@@ -275,17 +294,28 @@ class Server:
                             req.ticket._reject(Overloaded(
                                 t.name, "shutdown",
                                 queue_depth=len(t.queue)))
-                    batch = None
-                elif len(inflight) < self.max_inflight:
-                    batch = self._form_batch(full_only=bool(inflight))
-            if batch is not None:
-                tenant, reqs = batch
+                form = len(inflight) < self.max_inflight and self._has_queued()
+            if form:
                 try:
-                    xs = np.stack([r.x for r in reqs])
-                    disp = tenant.session.dispatch_async(xs)
+                    # the span covers selection, take and stack; the lock is
+                    # held for the first two only, as before
+                    with TraceAnnotation("serve.form") as span:
+                        with self._lock:
+                            batch = self._form_batch(full_only=bool(inflight))
+                        if batch is not None:
+                            tenant, reqs = batch
+                            span.set_metadata(batch=reqs[0].ticket.batch)
+                            xs = np.stack([r.x for r in reqs])
+                    if batch is not None:
+                        # the session's spans carry the Server's dispatch id
+                        tenant.session._next_batch = reqs[0].ticket.batch
+                        disp = tenant.session.dispatch_async(xs)
                 except Exception as e:  # noqa: BLE001 — isolate the batch
+                    if batch is None:
+                        raise
                     self._fail_batch(tenant, reqs, e)
                     continue
+            if batch is not None:
                 inflight.append((disp, reqs, tenant))
                 if len(inflight) < self.max_inflight:
                     continue    # keep the device pipe full before blocking
@@ -297,12 +327,13 @@ class Server:
                         break
 
     def _fail_batch(self, tenant: _Tenant, reqs, error: BaseException) -> None:
-        for r in reqs:
-            r.ticket._reject(error)
-        self.monitor.on_failure(tenant.name, len(reqs))
-        with self._lock:
-            self._inflight_batches -= 1
-            self._work.notify()
+        with TraceAnnotation("serve.fulfill", batch=reqs[0].ticket.batch):
+            for r in reqs:
+                r.ticket._reject(error)
+            self.monitor.on_failure(tenant.name, len(reqs))
+            with self._lock:
+                self._inflight_batches -= 1
+                self._work.notify()
 
     def _complete(self, disp, reqs, tenant: _Tenant) -> None:
         try:
@@ -310,14 +341,15 @@ class Server:
         except Exception as e:  # noqa: BLE001 — isolate the batch
             self._fail_batch(tenant, reqs, e)
             return
-        now = self._clock()
-        for r, y in zip(reqs, outs):
-            r.ticket._fulfill(np.asarray(y))
-        self.monitor.on_complete_batch(
-            tenant.name, [now - r.t_arrival for r in reqs])
-        with self._lock:
-            self._inflight_batches -= 1
-            self._work.notify()
+        with TraceAnnotation("serve.fulfill", batch=reqs[0].ticket.batch):
+            now = self._clock()
+            for r, y in zip(reqs, outs):
+                r.ticket._fulfill(np.asarray(y))
+            self.monitor.on_complete_batch(
+                tenant.name, [now - r.t_arrival for r in reqs])
+            with self._lock:
+                self._inflight_batches -= 1
+                self._work.notify()
 
 
 __all__ = ["Server", "SLO", "Overloaded", "QosMonitor", "TenantQos"]

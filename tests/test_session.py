@@ -218,14 +218,13 @@ class TestTicketHardening:
         session = Session(plan, precision="int8", qmodel=qmodel, max_batch=4,
                           buckets=(1, 2, 4))
         s0 = session.stats()
-        assert np.isnan(s0.latency_p50_s) and np.isnan(s0.latency_p99_s)
+        assert np.isnan(s0.latency_p50_s)
         assert s0.per_bucket_p50_s == {}
         session.submit_many(xs)             # 7 -> buckets 4 + 4(pad 1)
         s = session.stats()
         assert s.latency_p50_s > 0
-        assert s.latency_p99_s >= s.latency_p50_s
         assert set(s.per_bucket_p50_s) == set(s.per_bucket) == {4}
-        assert s.per_bucket_p99_s[4] >= s.per_bucket_p50_s[4] > 0
+        assert s.per_bucket_p50_s[4] > 0
         # the same rolling window answers the admission-control query
         assert session.dispatch_latency_s(bucket=4) == s.per_bucket_p50_s[4]
         assert np.isnan(session.dispatch_latency_s(bucket=2))
