@@ -2,35 +2,43 @@
 ReLU6 + requantization (MobileNetV2's hot-spot op, §VI).
 
 Depthwise conv has no reduction over channels, so it is VPU (not MXU) work:
-each grid step loads a (block_c, rows, cols) tile of the pre-padded input
-into VMEM and accumulates the 9 shifted element-wise products in int32 —
-the whole channel tile's activations stay VMEM-resident through the
-epilogue.  Channels are independent ("kernel-wise" in the paper's
-splitting), so the channel grid dimension is also the natural split axis.
+9 shifted element-wise products accumulated in int32, then a multiply-only
+f32 epilogue.  The kernel works **channels-last**: the wrapper transposes the
+(N, C, R, W) band stack to (N, R, W, C), so channels fill the 128-lane axis
+and the map width the sublanes, and transposes the int8 output back.  The
+callers (the executor is CHW) see the same shapes in and out.
+
+One grid step covers a block of many stack entries (samples × bands) and
+channels, sized from the shape by :func:`dwconv_blocks`; inside a step the
+kernel loops over the entries and over chunks of output rows, so the code
+Mosaic emits stays the size of one row chunk whatever the block holds.
 
 Layout rules the TPU compiler (Mosaic) imposes, and how the kernel meets
 them:
 
-* per-channel operands (tap weights, scale, bias) arrive as ``(..., C, 1, 1)``
-  arrays blocked ``(block_c, 1, 1)``: a block's last two dims equal the
-  array's, and ``(bc, 1, 1)`` broadcasts against the ``(bc, oh, ow)``
-  accumulator without any in-kernel reshape;
+* per-channel operands (tap weights, scale, bias) arrive as ``(..., 1, C)``
+  lane rows: a block's last two dims equal the array's or tile it, and
+  ``(1, cb)`` broadcasts against the ``(rows, cols, cb)`` accumulator;
 * stride 2 never slices a loaded value with a step: the wrapper splits the
-  padded input into ``stride**2`` row/column phases (a plain reshape +
-  transpose in XLA), so tap ``(i, j)`` is the unit-stride window of phase
-  ``(i % s, j % s)`` at offset ``(i // s, j // s)`` — a static ref load.
+  padded input into ``stride**2`` row/column phases (a reshape + transpose in
+  XLA, folded into the channels-last transpose), so tap ``(i, j)`` is the
+  unit-stride window of phase ``(i % s, j % s)`` at offset
+  ``(i // s, j // s)``;
+* the column offset of a tap is a sublane offset, which Mosaic takes only on
+  32-bit values: a row chunk is widened to int32 once, then windowed.
 
 :func:`dwconv3x3_bands` takes a stack of spatial band windows
-(bands, C, R, W+2): the **band index is a grid axis**, so every band of a
-fused spatial block executes in a single ``pallas_call`` instead of one
-dispatch per band (the split-executor hot path).  Rows beyond a band's valid
-window are zero-filled by the caller and their outputs discarded, so
-heterogeneous band heights ride one uniform grid.  :func:`dwconv3x3` is the
-one-sample case (a stack of one band).
+(bands, C, R, W+2).  Under ``jax.vmap`` (the executor's ``run_batch``) a
+``custom_vmap`` rule folds the batch into the stack, so B samples × bands
+reach the kernel as one stack, not as a grid axis with a block of one
+sample.  Rows beyond a band's valid window are zero-filled by the caller and
+their outputs discarded, so heterogeneous band heights ride one uniform
+stack.  :func:`dwconv3x3` is the one-sample case (a stack of one band).
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -38,11 +46,88 @@ from jax.experimental import pallas as pl
 
 from ..backend import resolve_interpret
 
+# Estimated VMEM of one grid step (double-buffered blocks plus the row
+# chunk's temporaries), kept well under v5e's 16 MiB scoped VMEM.
+VMEM_BUDGET = 6 * 2**20
+# int32 vregs (8 sublanes x 128 lanes) one widened input row chunk may span
+CHUNK_VREGS = 16
+
+
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _tile_bytes(rows: int, cols: int, lanes: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols, lanes) array: lanes pad to 128 and cols
+    to the dtype's sublane tile (32 rows for int8, 8 for 32-bit)."""
+    sub = 32 // itemsize
+    return rows * _cdiv(cols, sub) * sub * _cdiv(lanes, 128) * 128 * itemsize
+
+
+class Geometry(NamedTuple):
+    oh: int       # output rows
+    ow: int       # output cols
+    phases: int   # stride**2 row/column phases of the input
+    hh: int       # rows of one phase
+    ww: int       # cols of one phase
+
+
+def geometry(rows: int, cols: int, stride: int) -> Geometry:
+    oh = (rows - 3) // stride + 1
+    ow = (cols - 3) // stride + 1
+    if stride == 1:
+        return Geometry(oh, ow, 1, rows, cols)
+    return Geometry(oh, ow, stride * stride, oh + 2 // stride,
+                    ow + 2 // stride)
+
+
+class Blocks(NamedTuple):
+    stack: int      # stack entries (samples x bands) per grid step
+    channels: int   # channels per grid step: all of C, or a multiple of 128
+    rows: int       # output rows per in-kernel chunk
+
+
+def step_vmem_bytes(stack: int, channels: int, rows: int, g: Geometry) -> int:
+    """Estimated VMEM of one grid step: input, output and per-channel blocks,
+    each double-buffered, plus the int32 temporaries of one row chunk."""
+    x = stack * g.phases * _tile_bytes(g.hh, g.ww, channels, 1)
+    out = stack * max(_tile_bytes(g.oh, g.ow, channels, 1),
+                      _tile_bytes(g.oh, g.ow, channels, 4))
+    per_channel = 11 * _tile_bytes(1, 1, channels, 4)
+    slab = _tile_bytes(rows + 2, g.ww, channels, 4)
+    # widened phases, their column shifts, the accumulator and epilogue
+    temps = 3 * g.phases * slab + 4 * _tile_bytes(rows, g.ow, channels, 4)
+    return 2 * (x + out + per_channel) + temps
+
+
+def _divisors(n: int) -> list[int]:
+    return [d for d in range(1, n + 1) if n % d == 0]
+
+
+def dwconv_blocks(n: int, rows: int, cols: int, c: int,
+                  stride: int) -> Blocks:
+    """The blocks for a stack of ``n`` windows of (c, rows, cols): the
+    widest channel block (all of C, else a multiple of 128) whose step fits
+    :data:`VMEM_BUDGET`, the largest divisor of the output rows whose
+    widened input chunk spans at most :data:`CHUNK_VREGS` vregs, and the
+    largest divisor of ``n`` whose step fits the budget.  A shape no block
+    fits gets the smallest blocks, and Mosaic reports what does not fit."""
+    g = geometry(rows, cols, stride)
+    widths = [c, *range(128 * (_cdiv(c, 128) - 1), 0, -128)]
+    cb = next((k for k in widths
+               if step_vmem_bytes(1, k, 1, g) <= VMEM_BUDGET), min(c, 128))
+    per_row = _cdiv(g.ww, 8) * _cdiv(cb, 128)
+    rb = max(r for r in _divisors(g.oh)
+             if r == 1 or (r + 2) * per_row <= CHUNK_VREGS)
+    nb = max(d for d in _divisors(n)
+             if d == 1 or step_vmem_bytes(d, cb, rb, g) <= VMEM_BUDGET)
+    return Blocks(nb, cb, rb)
+
 
 def _epilogue(acc, scale, bias, *, activation: str | None,
               out_scale: float | None, int_bias: bool, out_dtype):
-    """Fused folded-BN + activation + requantization epilogue on a
-    (bc, oh, ow) int32 accumulator (scale/bias are (bc, 1, 1))."""
+    """Fused folded-BN + activation + requantization epilogue on an int32
+    accumulator (scale/bias broadcast along its last, channel, axis)."""
     if int_bias:
         # b_q added in exact int32; float steps are multiplies only so the
         # result is bit-identical to the executors' jnp epilogue (no
@@ -61,99 +146,142 @@ def _epilogue(acc, scale, bias, *, activation: str | None,
 
 
 def _dwconv_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, *, stride: int,
-                   activation: str | None, out_scale: float | None,
-                   int_bias: bool):
-    # x_ref: (1, s*s, bc, hh, ww) int8 phases; w_ref: (9, bc, 1, 1) int32;
-    # scale_ref/bias_ref: (bc, 1, 1); o_ref: (1, bc, oh, ow)
-    _, bc, oh, ow = o_ref.shape
-    acc = jnp.zeros((bc, oh, ow), jnp.int32)
-    for i in range(3):
-        for j in range(3):
-            phase = (i % stride) * stride + j % stride
-            win = x_ref[0, phase, :, pl.ds(i // stride, oh),
-                        pl.ds(j // stride, ow)]
-            acc = acc + win.astype(jnp.int32) * w_ref[3 * i + j]
-    o_ref[0] = _epilogue(acc, scale_ref[...], bias_ref[...],
-                         activation=activation, out_scale=out_scale,
-                         int_bias=int_bias, out_dtype=o_ref.dtype)
+                   rows: int, activation: str | None,
+                   out_scale: float | None, int_bias: bool):
+    # x_ref: (nb, s*s, hh, ww, cb) int8 phases; w_ref: (9, 1, cb) int32;
+    # scale_ref/bias_ref: (1, cb); o_ref: (nb, oh, ow, cb)
+    nb, oh, ow, _ = o_ref.shape
+    extra = 2 // stride         # input rows a chunk needs beyond its own
+    taps = [w_ref[k] for k in range(9)]
+    scale, bias = scale_ref[...], bias_ref[...]
+
+    def chunk(t, carry):
+        n, r0 = t // (oh // rows), (t % (oh // rows)) * rows
+        slabs = {}
+        acc = None
+        for i in range(3):
+            for j in range(3):
+                p, q, a, b = i % stride, j % stride, i // stride, j // stride
+                if (p, q) not in slabs:
+                    slabs[p, q] = x_ref[n, p * stride + q,
+                                        pl.ds(r0, rows + extra)
+                                        ].astype(jnp.int32)
+                if (p, q, b) not in slabs:
+                    slabs[p, q, b] = slabs[p, q][:, b:b + ow]
+                term = slabs[p, q, b][a:a + rows] * taps[3 * i + j]
+                acc = term if acc is None else acc + term
+        o_ref[n, pl.ds(r0, rows)] = _epilogue(
+            acc, scale, bias, activation=activation, out_scale=out_scale,
+            int_bias=int_bias, out_dtype=o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, nb * (oh // rows), chunk, 0)
 
 
-def _phase_split(x, stride: int, oh: int, ow: int):
-    """(B, C, R, Wp) -> (B, s*s, C, oh + 2//s, ow + 2//s): phase ``p*s + q``
-    holds rows ``p::s`` and columns ``q::s``, cropped or zero-padded to the
-    common extent every tap window needs.  Stride 1 is one phase, as is."""
+def _channels_last_phases(x, stride: int, g: Geometry):
+    """(N, C, R, Wp) -> (N, s*s, hh, ww, C): phase ``p*s + q`` holds rows
+    ``p::s`` and columns ``q::s``, cropped or zero-padded to the common
+    extent every tap window needs.  Stride 1 is one phase, as is."""
     if stride == 1:
-        return x[:, None]
-    b, c, _, _ = x.shape
-    hh, ww = oh + 2 // stride, ow + 2 // stride
-    x = x[:, :, :stride * hh, :stride * ww]
-    x = jnp.pad(x, ((0, 0), (0, 0), (0, stride * hh - x.shape[2]),
-                    (0, stride * ww - x.shape[3])))
-    x = x.reshape(b, c, hh, stride, ww, stride).transpose(0, 3, 5, 1, 2, 4)
-    return x.reshape(b, stride * stride, c, hh, ww)
+        return x.transpose(0, 2, 3, 1)[:, None]
+    n, c, _, _ = x.shape
+    x = x[:, :, :stride * g.hh, :stride * g.ww]
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, stride * g.hh - x.shape[2]),
+                    (0, stride * g.ww - x.shape[3])))
+    x = x.reshape(n, c, g.hh, stride, g.ww, stride).transpose(0, 3, 5, 2, 4, 1)
+    return x.reshape(n, stride * stride, g.hh, g.ww, c)
+
+
+def _dwconv_stack(x_win, w, scale, bias, *, stride: int,
+                  activation: str | None, out_scale: float | None,
+                  interpret: bool):
+    """The kernel on one (N, C, R, Wp) stack; returns (N, C, oh, ow)."""
+    n, c, rp, wp = x_win.shape
+    g = geometry(rp, wp, stride)
+    blk = dwconv_blocks(n, rp, wp, c, stride)
+    cp = _cdiv(c, blk.channels) * blk.channels
+    phases = _channels_last_phases(x_win, stride, g)
+    taps = w.reshape(c, 9).T.astype(jnp.int32)[:, None]
+    scale, bias = scale.reshape(1, c), jnp.asarray(bias).reshape(1, c)
+    if cp != c:
+        pad = ((0, 0),) * 4 + ((0, cp - c),)
+        phases = jnp.pad(phases, pad)
+        taps, scale, bias = (jnp.pad(a, pad[-a.ndim:])
+                             for a in (taps, scale, bias))
+    int_bias = jnp.issubdtype(bias.dtype, jnp.integer)
+    out_dtype = jnp.int8 if out_scale is not None else jnp.float32
+    kernel = functools.partial(_dwconv_kernel, stride=stride, rows=blk.rows,
+                               activation=activation, out_scale=out_scale,
+                               int_bias=int_bias)
+    nb, cb = blk.stack, blk.channels
+    per_channel = pl.BlockSpec((1, cb), lambda si, ci: (0, ci))
+    out = pl.pallas_call(
+        kernel,
+        grid=(n // nb, cp // cb),
+        in_specs=[
+            pl.BlockSpec((nb, g.phases, g.hh, g.ww, cb),
+                         lambda si, ci: (si, 0, 0, 0, ci)),
+            pl.BlockSpec((9, 1, cb), lambda si, ci: (0, 0, ci)),
+            per_channel,
+            per_channel,
+        ],
+        out_specs=pl.BlockSpec((nb, g.oh, g.ow, cb),
+                               lambda si, ci: (si, 0, 0, ci)),
+        out_shape=jax.ShapeDtypeStruct((n, g.oh, g.ow, cp), out_dtype),
+        interpret=interpret,
+    )(phases, taps, scale, bias)
+    return out[..., :c].transpose(0, 3, 1, 2)
+
+
+def _stack_folding(fn):
+    """``fn`` on a (N, C, R, Wp) stack, with a vmap rule that folds a
+    batched stack (B, N, ...) into one (B*N, ...) stack and calls ``fn``
+    once.  Batched weights (never the executor's case) vmap ``fn`` as is."""
+    folded = jax.custom_batching.custom_vmap(fn)
+
+    @folded.def_vmap
+    def _rule(axis_size, in_batched, x, w, scale, bias):
+        if any(in_batched[1:]) or not in_batched[0]:
+            axes = tuple(0 if b else None for b in in_batched)
+            return jax.vmap(fn, in_axes=axes)(x, w, scale, bias), True
+        y = folded(x.reshape(-1, *x.shape[2:]), w, scale, bias)
+        return y.reshape(axis_size, -1, *y.shape[1:]), True
+
+    return folded
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "activation",
-                                             "out_scale", "block_c",
-                                             "interpret"))
+                                             "out_scale", "interpret"))
 def dwconv3x3_bands(x_win, w, scale, bias, *, stride: int = 1,
                     activation: str | None = None,
                     out_scale: float | None = None,
-                    block_c: int = 8, interpret: bool | None = None):
+                    interpret: bool | None = None):
     """Batched-band 3x3 depthwise conv: ``x_win`` is (bands, C, R, W+2) int8
     — one pre-gathered row window per spatial band (halo/zero rows and the
     width pad already in place, shorter bands zero-filled to the common R).
 
-    The band index is the leading **grid axis** (grid = (bands, C//block_c)),
-    so a fused spatial block's depthwise stage is ONE kernel invocation for
-    the whole cluster instead of one dispatch per band.  The per-channel
-    weight/scale/bias tiles are selected by the channel ``program_id``,
-    shared across bands (spatial mode replicates weights).
+    Every band of a fused spatial block (and, under ``jax.vmap``, every
+    sample's bands) runs in one kernel invocation of a few grid steps; the
+    per-channel weight/scale/bias are shared across the stack (spatial mode
+    replicates weights).
 
     ``w``: (C, 3, 3) int8; ``scale``: (C,) f32; ``bias``: (C,) f32
     (real-domain, f32 epilogue) or int32 (quantized ``b_q``, added in exact
     int32 — the bit-exact executor path).  Returns (bands, C, oh, ow) int8
-    (requantized at ``out_scale``) or f32.  C must be a multiple of
-    ``block_c`` (ops.py pads).  ``interpret=None`` auto-detects: compiled on
-    TPU, interpret elsewhere.
+    (requantized at ``out_scale``) or f32.  ``interpret=None`` auto-detects:
+    compiled on TPU, interpret elsewhere.
     """
-    interpret = resolve_interpret(interpret)
-    b, c, rp, wp = x_win.shape
-    assert c % block_c == 0
-    oh = (rp - 3) // stride + 1
-    ow = (wp - 3) // stride + 1
-    phases = _phase_split(x_win, stride, oh, ow)
-    _, n_ph, _, hh, ww = phases.shape
-    taps = w.reshape(c, 9).T.astype(jnp.int32).reshape(9, c, 1, 1)
-    bias = jnp.asarray(bias)
-    int_bias = jnp.issubdtype(bias.dtype, jnp.integer)
-    out_dtype = jnp.int8 if out_scale is not None else jnp.float32
-    kernel = functools.partial(_dwconv_kernel, stride=stride,
-                               activation=activation, out_scale=out_scale,
-                               int_bias=int_bias)
-    per_channel = pl.BlockSpec((block_c, 1, 1), lambda bi, ci: (ci, 0, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(b, c // block_c),
-        in_specs=[
-            pl.BlockSpec((1, n_ph, block_c, hh, ww),
-                         lambda bi, ci: (bi, 0, ci, 0, 0)),
-            pl.BlockSpec((9, block_c, 1, 1), lambda bi, ci: (0, ci, 0, 0)),
-            per_channel,
-            per_channel,
-        ],
-        out_specs=pl.BlockSpec((1, block_c, oh, ow),
-                               lambda bi, ci: (bi, ci, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, c, oh, ow), out_dtype),
-        interpret=interpret,
-    )(phases, taps, scale.reshape(c, 1, 1), bias.reshape(c, 1, 1))
+    fn = functools.partial(_dwconv_stack, stride=stride,
+                           activation=activation, out_scale=out_scale,
+                           interpret=resolve_interpret(interpret))
+    return _stack_folding(fn)(x_win, w, scale, bias)
 
 
 def dwconv3x3(x_pad, w, scale, bias, *, stride: int = 1,
               activation: str | None = None, out_scale: float | None = None,
-              block_c: int = 8, interpret: bool | None = None):
+              interpret: bool | None = None):
     """One sample: ``x_pad`` is (C, H+2, W+2) int8 (pre-padded by 1); same
     contract as :func:`dwconv3x3_bands` otherwise.  Returns (C, oh, ow)."""
     return dwconv3x3_bands(x_pad[None], w, scale, bias, stride=stride,
                            activation=activation, out_scale=out_scale,
-                           block_c=block_c, interpret=interpret)[0]
+                           interpret=interpret)[0]

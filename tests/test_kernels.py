@@ -1,13 +1,29 @@
 """Pallas kernel validation: shape/dtype sweeps, assert_allclose vs the
 ref.py pure-jnp oracles (interpret=True on CPU; TPU is the target)."""
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels.decode_attn.ops import flash_decode, flash_decode_ref
+from repro.kernels.dwconv.dwconv import dwconv3x3_bands, dwconv_blocks
 from repro.kernels.dwconv.ops import dwconv, dwconv_bands, dwconv_ref
 from repro.kernels.dwconv.ref import dwconv3x3_ref
 from repro.kernels.qgemm.ops import (qconv2d, qconv2d_ref, qgemm_padded)
 from repro.kernels.qgemm.ref import qgemm_ref
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs in its parameters."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
 
 
 @pytest.fixture
@@ -77,7 +93,8 @@ class TestQGEMM:
 
 
 class TestDWConv:
-    @pytest.mark.parametrize("c,hw", [(8, 16), (19, 12), (32, 7)])
+    @pytest.mark.parametrize("c,hw", [(8, 16), (19, 12), (32, 7), (50, 9),
+                                      (960, 4)])
     @pytest.mark.parametrize("stride", [1, 2])
     def test_sweep_vs_ref(self, rng, c, hw, stride):
         x = rng.integers(-127, 128, (c, hw, hw)).astype(np.int8)
@@ -92,7 +109,8 @@ class TestDWConv:
         assert np.max(np.abs(np.asarray(got, np.int32)
                              - np.asarray(exp, np.int32))) <= 1
 
-    @pytest.mark.parametrize("c,hw", [(8, 16), (24, 13), (32, 7)])
+    @pytest.mark.parametrize("c,hw", [(8, 16), (24, 13), (32, 7), (29, 14),
+                                      (246, 4)])
     @pytest.mark.parametrize("stride", [1, 2])
     def test_int_bias_bit_exact(self, rng, c, hw, stride):
         """The executors' contract: int32 bias + multiply-only epilogue
@@ -121,6 +139,107 @@ class TestDWConv:
             exp = dwconv3x3_ref(x[i], w, s, b, stride=stride,
                                 activation="relu6", out_scale=0.05)
             np.testing.assert_array_equal(got[i], np.asarray(exp))
+
+    # (batch, bands, C, rows, cols, stride, bias): run_batch vmaps the
+    # kernel over the batch; band stacks and channel counts of the plans
+    @pytest.mark.parametrize("batch,bands,c,rows,cols,stride,bias", [
+        (1, 7, 29, 9, 10, 1, "int"),
+        (1, 4, 960, 3, 6, 1, "float"),
+        (1, 1, 50, 16, 16, 2, "int"),
+        (8, 7, 20, 5, 12, 2, "int"),
+        (8, 4, 50, 4, 9, 1, "float"),
+        (8, 1, 246, 6, 6, 1, "int"),
+        (8, 7, 960, 3, 6, 2, "float"),
+        (32, 1, 246, 6, 6, 1, "int"),
+        (32, 1, 29, 9, 9, 2, "float"),
+        (32, 4, 960, 3, 6, 1, "int"),
+        (32, 7, 20, 13, 14, 2, "int"),
+    ])
+    def test_vmapped_stack_bit_exact(self, rng, batch, bands, c, rows, cols,
+                                     stride, bias):
+        """The batch folded into the stack equals every sample's every band
+        alone through the oracle: bit for bit with the int32 bias; within
+        one output step with a float bias, whose ``acc * scale + bias`` may
+        contract to an FMA on one side only (as in ``test_sweep_vs_ref``)."""
+        x = rng.integers(-127, 128, (batch, bands, c, rows, cols)
+                         ).astype(np.int8)
+        w = rng.integers(-127, 128, (c, 3, 3)).astype(np.int8)
+        s = rng.uniform(1e-4, 1e-3, c).astype(np.float32)
+        b = (rng.integers(-5000, 5000, c).astype(np.int32) if bias == "int"
+             else rng.uniform(-1, 1, c).astype(np.float32))
+        fn = jax.vmap(functools.partial(dwconv3x3_bands, stride=stride,
+                                        activation="relu6", out_scale=0.05),
+                      in_axes=(0, None, None, None))
+        got = np.asarray(fn(x, w, s, b))
+        for i in range(batch):
+            for j in range(bands):
+                exp = np.asarray(dwconv3x3_ref(
+                    x[i, j], w, s, b, stride=stride, activation="relu6",
+                    out_scale=0.05))
+                if bias == "int":
+                    np.testing.assert_array_equal(got[i, j], exp)
+                else:
+                    assert np.max(np.abs(got[i, j].astype(np.int32)
+                                         - exp)) <= 1
+
+    @pytest.mark.parametrize("stride", [1, 2])
+    def test_channel_blocks_narrower_than_c(self, rng, monkeypatch, stride):
+        """Where one entry with all channels would pass the VMEM budget, the
+        channels split into blocks of 128 lanes and C is zero-padded to a
+        whole block (shown here under a small budget)."""
+        from repro.kernels.dwconv import dwconv as kernel
+        monkeypatch.setattr(kernel, "VMEM_BUDGET", 2**19)
+        bands, c, rows, cols = 3, 300, 7 + stride, 10
+        assert dwconv_blocks(bands, rows, cols, c, stride).channels == 128
+        x = rng.integers(-127, 128, (bands, c, rows, cols)).astype(np.int8)
+        w = rng.integers(-127, 128, (c, 3, 3)).astype(np.int8)
+        s = rng.uniform(1e-4, 1e-3, c).astype(np.float32)
+        b = rng.integers(-5000, 5000, c).astype(np.int32)
+        got = np.asarray(dwconv3x3_bands(x, w, s, b, stride=stride,
+                                         activation="relu6", out_scale=0.05))
+        for i in range(bands):
+            exp = dwconv3x3_ref(x[i], w, s, b, stride=stride,
+                                activation="relu6", out_scale=0.05)
+            np.testing.assert_array_equal(got[i], np.asarray(exp))
+
+    @pytest.mark.parametrize("batch,bands,c,rows,cols,stride", [
+        (8, 7, 32, 15, 58, 1), (32, 1, 246, 6, 6, 1), (8, 4, 960, 3, 6, 1)])
+    def test_vmap_folds_into_one_call(self, batch, bands, c, rows, cols,
+                                      stride):
+        """Under vmap the kernel is one pallas_call whose grid is the
+        blocks' (stack chunks, channel blocks), with no batch axis."""
+        fn = jax.vmap(functools.partial(dwconv3x3_bands, stride=stride,
+                                        out_scale=0.05),
+                      in_axes=(0, None, None, None))
+        jaxpr = jax.make_jaxpr(fn)(
+            jnp.zeros((batch, bands, c, rows, cols), jnp.int8),
+            jnp.zeros((c, 3, 3), jnp.int8), jnp.zeros(c, jnp.float32),
+            jnp.zeros(c, jnp.int32))
+        grids = [e.params["grid_mapping"].grid
+                 for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+        blk = dwconv_blocks(batch * bands, rows, cols, c, stride)
+        assert grids == [(batch * bands // blk.stack,
+                          -(-c // blk.channels))]
+
+    def test_vmap_over_weights_and_nested(self, rng):
+        """Batched weights fall back to vmapping the call; a vmap of a vmap
+        folds both batch axes into the stack."""
+        x = rng.integers(-127, 128, (2, 3, 2, 11, 7, 8)).astype(np.int8)
+        w = rng.integers(-127, 128, (2, 11, 3, 3)).astype(np.int8)
+        s = rng.uniform(1e-4, 1e-3, 11).astype(np.float32)
+        b = rng.integers(-5000, 5000, 11).astype(np.int32)
+        call = functools.partial(dwconv3x3_bands, activation="relu6",
+                                 out_scale=0.05)
+        nested = jax.vmap(jax.vmap(call, in_axes=(0, None, None, None)),
+                          in_axes=(0, 0, None, None))
+        got = np.asarray(nested(x, w, s, b))
+        for i in range(2):
+            for k in range(3):
+                for j in range(2):
+                    exp = dwconv3x3_ref(x[i, k, j], w[i], s, b,
+                                        activation="relu6", out_scale=0.05)
+                    np.testing.assert_array_equal(got[i, k, j],
+                                                  np.asarray(exp))
 
     def test_float_out(self, rng):
         x = rng.integers(-127, 128, (8, 10, 10)).astype(np.int8)

@@ -14,14 +14,18 @@ one process may load the TPU library at a time.
 """
 import functools
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.api import Plan
 from repro.core import CompiledSplitExecutor, quantize_model, split_model
-from repro.kernels.dwconv.dwconv import dwconv3x3, dwconv3x3_bands
+from repro.kernels.dwconv.dwconv import (VMEM_BUDGET, dwconv3x3,
+                                         dwconv3x3_bands, dwconv_blocks,
+                                         geometry, step_vmem_bytes)
 from repro.kernels.qgemm.ops import qgemm_padded
 from repro.models import mobilenet_v2_paper
 
@@ -32,6 +36,15 @@ MNV2_112_DWCONV = [(32, 56, 1), (96, 56, 2), (144, 28, 1), (144, 28, 2),
                    (576, 7, 2), (960, 4, 1)]
 # uneven worker ratings, so band heights differ within a stack
 RATINGS = [4.0, 3.0, 3.0, 2.0, 2.0, 1.0, 1.0]
+# the benchmark's committed plans, with the batch bucket each is served at
+CONFIGS = pathlib.Path(__file__).resolve().parent.parent / "bench" / "configs"
+PLANS = {"spatial": ("mnv2_112_int8_spatial.plan.json", 8),
+         "neuron": ("mnv2_112_int8_neuron.plan.json", 32)}
+# grid steps the tiling may take for one kernel call, and for all of a
+# dispatch's calls, at each plan's bucket (it was 3,840 and 36,704 with
+# 8-channel, one-sample, one-band blocks)
+MAX_STEPS_PER_CALL = 32
+MAX_STEPS_PER_DISPATCH = {"spatial": 100, "neuron": 500}
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +91,31 @@ def _dw_specs(one_chip, x_shape):
             _spec(one_chip, (c,), jnp.int32))
 
 
+def planned_dwconv_calls(model, plan_file):
+    """(stack, C, rows, cols, stride) of every ``dwconv3x3_bands`` call the
+    executor makes for one sample of a committed plan: band stacks of the
+    spatial blocks, one-window stacks of the flat per-shard layers."""
+    plan = Plan.from_json(CONFIGS / plan_file, model).split
+    ex = CompiledSplitExecutor(plan)
+    calls = []
+    for idxs in plan.block_groups:
+        if plan.splits[idxs[0]].mode == "spatial":
+            for st in ex._banded_block(tuple(idxs)).stages:
+                layer = model.layers[st.index]
+                if layer.kind == "dwconv":
+                    calls.append((st.src_rows.shape[0], layer.in_shape[0],
+                                  st.src_rows.shape[1],
+                                  layer.in_shape[2] + 2, layer.stride[0]))
+            continue
+        for i in idxs:
+            layer = model.layers[i]
+            if layer.kind == "dwconv":
+                calls.extend((1, g.c_hi - g.c_lo + 1, layer.in_shape[1] + 2,
+                              layer.in_shape[2] + 2, layer.stride[0])
+                             for g in ex._geometry[i] if g is not None)
+    return calls
+
+
 def test_depthwise_shapes_match_model(model):
     got = sorted({(lyr.in_shape[0], lyr.in_shape[1], lyr.stride[0])
                   for lyr in model.layers if lyr.kind == "dwconv"})
@@ -114,6 +152,44 @@ def test_dwconv3x3_bands_compiles_on_planned_stacks(one_chip, model, fused):
                                activation="relu6", out_scale=0.05,
                                interpret=False)
         _compile(fn, *_dw_specs(one_chip, (bands, c, rows, cols)))
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_dwconv3x3_bands_vmapped_compiles_on_committed_plans(one_chip, model,
+                                                             plan):
+    """``run_batch`` vmaps the kernel: at the plan's batch bucket the batch
+    folds into the stack, on every stack of the committed plan."""
+    plan_file, batch = PLANS[plan]
+    for n, c, rows, cols, stride in sorted(set(
+            planned_dwconv_calls(model, plan_file))):
+        fn = jax.vmap(functools.partial(dwconv3x3_bands, stride=stride,
+                                        activation="relu6", out_scale=0.05,
+                                        interpret=False),
+                      in_axes=(0, None, None, None))
+        _compile(fn, *_dw_specs(one_chip, (batch, n, c, rows, cols)))
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_dwconv_tiling_on_committed_plans(model, plan):
+    """The blocks the tiling rule picks at every planned shape, at bucket 1
+    and at the plan's bucket: few grid steps a call and a dispatch, whole
+    blocks, and a VMEM estimate within the budget."""
+    plan_file, bucket = PLANS[plan]
+    calls = planned_dwconv_calls(model, plan_file)
+    assert calls
+    for batch in (1, bucket):
+        total = 0
+        for n, c, rows, cols, stride in calls:
+            blk = dwconv_blocks(batch * n, rows, cols, c, stride)
+            g = geometry(rows, cols, stride)
+            assert (batch * n) % blk.stack == 0 and g.oh % blk.rows == 0
+            assert blk.channels == c or blk.channels % 128 == 0
+            assert step_vmem_bytes(blk.stack, blk.channels, blk.rows,
+                                   g) <= VMEM_BUDGET
+            steps = batch * n // blk.stack * -(-c // blk.channels)
+            assert steps <= MAX_STEPS_PER_CALL
+            total += steps
+        assert total <= MAX_STEPS_PER_DISPATCH[plan]
 
 
 @pytest.mark.parametrize("batch,m,k,n", [
