@@ -5,7 +5,9 @@ The program is driven only through its served entry: ``Plan.from_json`` ->
 ``Plan.compile(precision="int8", qmodel=...)`` -> ``Server.add_tenant`` ->
 ``Server.submit`` -> the ticket.  The benchmark makes the weights, the
 inputs and the activation scales from the seed; the program quantizes the
-weights with its own ``quantize_model``.
+weights with its own ``quantize_model``.  The reference and the work counts
+are the configuration's, as ``spec.Benchmark.reference`` and ``.work``
+resolve them.
 """
 from __future__ import annotations
 
@@ -132,11 +134,12 @@ def setup(bm: spec.Benchmark, cell_name: str, seed: int, *,
         t[0] = now
 
     arch = bm.arch(cfg)
+    ref = bm.reference(cfg)
     layers = arch.layers(cfg)
     params = arch.make_params(cfg, seed)
     pool, calib = _pool(cfg, seed, int(traffic.get("input_pool", 64)))
     phase("weights and inputs")
-    scales = reference.calibrate(layers, params, calib)
+    scales = ref.calibrate(layers, params, calib)
     phase("calibration")
     model = spec.program_model(bm.bench, cfg, seed, params=params)
     plan = Plan.from_json(bm.bench / cfg["plan_file"], model)
@@ -151,7 +154,8 @@ def setup(bm: spec.Benchmark, cell_name: str, seed: int, *,
     return types.SimpleNamespace(
         bm=bm, cell=cell, cfg=cfg, traffic=traffic, device=device,
         counter=counter, layers=layers, params=params, pool=pool,
-        scales=scales, server=server, session=sess, log=log, seed=seed)
+        reference=ref, work=bm.work(cfg), scales=scales, server=server,
+        session=sess, log=log, seed=seed)
 
 
 def window(ctx, seconds: float, traced: bool = False,
@@ -199,16 +203,19 @@ def window(ctx, seconds: float, traced: bool = False,
         trace_dir=trace_dir if traced else None)
 
 
-def compare(layers, params, scales, pool, answers, limit) -> dict:
+def compare(ref, layers, params, scales, pool, answers, limit) -> dict:
     """The check that decides ``correct``: every answer served in the
-    window against the integer reference of its input."""
-    q = reference.quantize(layers, params, scales, 127)
+    window against the integer reference ``ref`` of its input.  With no
+    answer there is nothing to compare, and the gap reads None."""
+    if not answers:
+        return {"logit_gap_lsb": [None, limit]}
+    q = ref.quantize(layers, params, scales, 127)
     used = sorted({p for p, _ in answers})
-    ref = dict(zip(used, reference.int_forward(layers, q, pool[used])))
-    gap = (reference.logit_gap_lsb(np.stack([a for _, a in answers]),
-                                   q["out_scale"],
-                                   np.stack([ref[p] for p, _ in answers]),
-                                   q["out_scale"]) if answers else None)
+    want = dict(zip(used, ref.int_forward(layers, q, pool[used])))
+    gap = reference.logit_gap_lsb(np.stack([a for _, a in answers]),
+                                  q["out_scale"],
+                                  np.stack([want[p] for p, _ in answers]),
+                                  q["out_scale"])
     return {"logit_gap_lsb": [gap, limit]}
 
 
@@ -241,8 +248,8 @@ def run(bm: spec.Benchmark, cell_name: str, seed: int, seconds: float,
     CompiledSplitExecutor.cache_clear()
     gc.collect()
     t_ref = time.perf_counter()
-    checks = compare(ctx.layers, ctx.params, ctx.scales, ctx.pool, answers,
-                     ctx.cfg["correct"]["logit_gap_lsb"])
+    checks = compare(ctx.reference, ctx.layers, ctx.params, ctx.scales,
+                     ctx.pool, answers, ctx.cfg["correct"]["logit_gap_lsb"])
     unanswered = sum(r.status in ("missing", "error")
                      for r in rec.window_requests)
     checks["unanswered"] = [unanswered, 0]
@@ -252,7 +259,8 @@ def run(bm: spec.Benchmark, cell_name: str, seed: int, seconds: float,
         f"{time.perf_counter() - t_ref:.3f} s")
 
     rec.__dict__.update(cell=ctx.cell, cfg=ctx.cfg, traffic=ctx.traffic,
-                        layers=ctx.layers, setup_s=rec.window[0] - t_process,
+                        layers=ctx.layers, work=ctx.work,
+                        setup_s=rec.window[0] - t_process,
                         peak=(work.peaks(ctx.device["kind"])
                               if ctx.device["platform"] != "cpu" else None),
                         trace=None)

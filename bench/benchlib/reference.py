@@ -12,6 +12,11 @@ Weights and scales are arguments of the jitted functions, never constants,
 so one compiled program serves every seed.  Quantizing the weights and
 deriving the epilogue constants happens on the host in numpy, in the same
 float32 / float64 steps the configuration states.
+
+It knows the op kinds conv, dwconv, linear and avgpool and the activations
+None, relu and relu6, and raises ``ValueError`` on any other: an
+architecture built from more brings its own reference (see
+``spec.Benchmark.reference``).
 """
 from __future__ import annotations
 
@@ -22,6 +27,14 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
+KINDS = ("conv", "dwconv", "linear", "avgpool")
+ACTIVATIONS = (None, "relu", "relu6")
+
+
+def _unknown(what: str, value, known: tuple) -> ValueError:
+    return ValueError(f"the shared reference has no {what} {value!r} (it "
+                      f"knows {known}); the architecture module names the "
+                      "REFERENCE module that computes it")
 
 
 def _static(layers: list[dict]) -> tuple:
@@ -36,7 +49,9 @@ def _act(y, act):
         return jnp.clip(y, 0.0, 6.0)
     if act == "relu":
         return jnp.maximum(y, 0.0)
-    return y
+    if act is None:
+        return y
+    raise _unknown("activation", act, ACTIVATIONS)
 
 
 def _conv(x, w, stride, pad, groups=1, precision=None, out=None):
@@ -62,11 +77,13 @@ def _activation_maxes(params, x, struct):
             cur = jnp.dot(cur.reshape(cur.shape[0], -1), w,
                           precision=HIGHEST) + b
             cur = _act(cur, act).reshape(cur.shape[0], *out_shape)
-        else:
+        elif kind in ("conv", "dwconv"):
             w, b = prm
             groups = cur.shape[1] if kind == "dwconv" else 1
             cur = _conv(cur, w, s, p, groups, HIGHEST) + b[:, None, None]
             cur = _act(cur, act)
+        else:
+            raise _unknown("op kind", kind, KINDS)
         if res is not None:
             cur = cur + stash[res]
         if save_as is not None:
@@ -95,6 +112,10 @@ def quantize(layers, params, scales, qmax: int = 127) -> dict:
     layer_q = []
     scale_of = {}
     for i, (lyr, prm) in enumerate(zip(layers, params)):
+        if lyr["kind"] not in KINDS:
+            raise _unknown("op kind", lyr["kind"], KINDS)
+        if lyr["act"] not in ACTIVATIONS:
+            raise _unknown("activation", lyr["act"], ACTIVATIONS)
         s_in, s_out = float(scales[i]), float(scales[i + 1])
         if lyr["save_as"] is not None:
             scale_of[lyr["save_as"]] = s_out
@@ -155,7 +176,7 @@ def _int_forward(q, x, struct, qmax):
         if kind == "avgpool":
             tot = jnp.sum(cur, axis=(2, 3), keepdims=True)
             cur = _requant(tot.astype(jnp.float32), ql["pool"], qmax)
-        else:
+        elif kind in ("conv", "dwconv", "linear"):
             x8 = cur.astype(jnp.int8)
             if kind == "linear":
                 acc = jnp.dot(x8.reshape(x8.shape[0], -1), ql["w"],
@@ -172,6 +193,8 @@ def _int_forward(q, x, struct, qmax):
             y = (acc + bias).astype(jnp.float32) * mult
             cur = _requant(_act(y, act), ql["inv_out"], qmax)
             cur = cur.reshape(cur.shape[0], *out_shape)
+        else:
+            raise _unknown("op kind", kind, KINDS)
         if res is not None:
             r = jnp.round(stash[res].astype(jnp.float32) * ql["res"])
             cur = jnp.clip(cur + r.astype(jnp.int32), -qmax, qmax)

@@ -4,7 +4,8 @@ Everything that belongs to one configuration, one traffic mix or one metric
 lives in a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 * ``bench/configs/<config>.json`` — the configuration's sizes; its ``arch``
-  names the architecture module ``bench/models/<arch>.py``;
+  names the architecture module ``bench/models/<arch>.py``, and its
+  ``plan_file`` the committed plan beside it;
 * ``bench/traffic/<traffic>.json`` — the traffic mix's parameters, read by
   the one general generator (``benchlib.loadgen``);
 * ``bench/metrics/<metric>.json`` — a metric's reader
@@ -12,6 +13,34 @@ lives in a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 So a later cell, configuration, traffic mix or metric is added by adding
 files and entries, never by editing a file that is there.
+
+An architecture module gives ``layers(cfg)`` (the layer list: ``kind``,
+``name``, CHW ``in_shape`` and ``out_shape``, ``k``, ``stride``, ``pad``,
+``act``, ``save_as``, ``residual_from``), ``make_params(cfg, seed)`` (per
+layer a float32 (w, b), or None) and ``program_ops(cfg, params)`` (the op
+list the program builds its model from).  Where its layers hold an op kind
+or an activation that the shared reference (``benchlib.reference``) or the
+shared work counts (``benchlib.work``) do not know (they raise a
+``ValueError`` on one), the module names a module of its own for each, by
+file stem under ``bench/models/`` (it may name itself):
+
+* ``REFERENCE = "<stem>"`` — the plain reference.  It imports nothing of the
+  program and gives ``calibrate(layers, params, calib, qmax=127)``, the
+  activation scales (input first, then every layer's output) that the
+  program's ``quantize_model`` takes for the model ``program_ops`` builds;
+  ``quantize(layers, params, scales, qmax)``, a dict holding at least
+  ``out_scale`` (float) and ``qmax``; and ``int_forward(layers, q, x)``,
+  the integer logits (B, classes) of float32 inputs ``x``.  The comparison
+  (``benchlib.reference.logit_gap_lsb``) stays the shared one.
+* ``WORK = "<stem>"`` — the work counts: ``layer_macs(lyr)``,
+  ``weight_bytes(lyr)`` and ``activation_bytes(lyr)`` for every layer of
+  the list (each may be left out to keep the shared one), and ``FAMILIES``,
+  kernel family name -> layer selector, that a metric file's
+  ``params.family`` may name.  A shared family of the same name is found
+  first, so ``dwconv`` and ``qgemm`` read the same for every architecture.
+
+:meth:`Benchmark.reference` and :meth:`Benchmark.work` resolve them, and
+every caller goes through them; readers get the counts as ``rec.work``.
 """
 from __future__ import annotations
 
@@ -84,6 +113,24 @@ class Benchmark:
 
     def arch(self, cfg: dict):
         return load_module(self.bench / "models" / f"{cfg['arch']}.py")
+
+    def _own(self, cfg: dict, hook: str):
+        """The module the architecture names as ``hook``, or None."""
+        stem = getattr(self.arch(cfg), hook, None)
+        return (None if stem is None else
+                load_module(self.bench / "models" / f"{stem}.py"))
+
+    def reference(self, cfg: dict):
+        """The configuration's plain reference: its architecture's
+        ``REFERENCE`` module, else ``benchlib.reference``."""
+        from . import reference as shared
+        return self._own(cfg, "REFERENCE") or shared
+
+    def work(self, cfg: dict):
+        """The configuration's work counts (``benchlib.work.Counts``), with
+        its architecture's ``WORK`` module, if it names one."""
+        from .work import Counts
+        return Counts(self._own(cfg, "WORK"))
 
     def reader(self, name: str):
         return load_module(self.bench / "readers" / f"{name}.py")

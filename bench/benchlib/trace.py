@@ -97,6 +97,7 @@ class Trace:
         self.devices = sorted({e.plane for e in events
                                if DEVICE_PLANE.match(e.plane)})
         self._lines: dict[tuple, list[Event]] = {}
+        self._busy: dict[str, list[tuple[float, float]]] = {}
 
     @property
     def window_s(self) -> float:
@@ -117,8 +118,13 @@ class Trace:
         return self.line(MODULES_LINE, device)
 
     def busy(self, device: str) -> list[tuple[float, float]]:
-        return clip(union((e.start_ns, e.end_ns) for e in self.ops(device)),
-                    self.lo, self.hi)
+        """The device's merged op intervals in the window, merged once (a
+        traced run's millions of ops take seconds to sort)."""
+        if device not in self._busy:
+            self._busy[device] = clip(
+                union((e.start_ns, e.end_ns) for e in self.ops(device)),
+                self.lo, self.hi)
+        return self._busy[device]
 
     def busy_s(self) -> float:
         """Seconds in the window in which an op ran, averaged over the

@@ -1,8 +1,8 @@
 """The whole program's share (%) of the int8 peak while it runs: integer
 ops of the real (unpadded) samples the traced run served, over the device
 time of the program's executions times the peak.  Ops are the algorithm's
-(``benchlib.work``), so padding and recompute lower the share."""
-from benchlib import work
+(``rec.work``, the configuration's counts), so padding and recompute lower
+the share."""
 
 
 def read(rec, params):
@@ -12,5 +12,5 @@ def read(rec, params):
     if not runs or not samples:
         return None
     t = sum(e.dur_ns for e in runs) * 1e-9
-    ops = samples * work.ops_per_sample(rec.layers)
+    ops = samples * rec.work.ops_per_sample(rec.layers)
     return 100.0 * ops / (t * rec.peak["int8_ops_per_s"])

@@ -1,6 +1,6 @@
 """Served work's share (%) of the chip's int8 peak over the window:
-completed requests per second x integer ops per sample / peak."""
-from benchlib import work
+completed requests per second x integer ops per sample (``rec.work``, the
+configuration's counts) / peak."""
 
 
 def read(rec, params):
@@ -9,5 +9,5 @@ def read(rec, params):
     if not done or rec.peak is None:
         return None
     rate = done / rec.seconds
-    return 100.0 * rate * work.ops_per_sample(rec.layers) / \
+    return 100.0 * rate * rec.work.ops_per_sample(rec.layers) / \
         rec.peak["int8_ops_per_s"]

@@ -3,7 +3,8 @@
     python3 bench/tools/control.py --workload mnv2-spatial-steady \\
         --seeds 1,2,3
 
-The control is the plain reference put in the program's place at the
+The control is the configuration's plain reference (as
+``spec.Benchmark.reference`` resolves it) put in the program's place at the
 nearest precision below the configuration's: int4 weights and activations
 (scales max|.|/7) for the int8 configuration.  For every seed it makes the
 cell's weights and input pool, and prints the number the cell's check
@@ -44,19 +45,18 @@ def main() -> int:
     c = bm.cell(args.workload)
     cell.device_info(jax, c.chips)
     arch = bm.arch(c.config)
+    plain = bm.reference(c.config)
     layers = arch.layers(c.config)
     for seed in (int(s) for s in args.seeds.split(",")):
         params = arch.make_params(c.config, seed)
         pool, calib = cell._pool(c.config, seed,
                                  int(c.traffic.get("input_pool", 64)))
-        q8 = reference.quantize(layers, params,
-                                reference.calibrate(layers, params, calib),
-                                127)
-        q4 = reference.quantize(layers, params,
-                                reference.calibrate(layers, params, calib, 7),
-                                7)
-        ref = reference.int_forward(layers, q8, pool)
-        ctl = reference.int_forward(layers, q4, pool)
+        q8 = plain.quantize(layers, params,
+                            plain.calibrate(layers, params, calib), 127)
+        q4 = plain.quantize(layers, params,
+                            plain.calibrate(layers, params, calib, 7), 7)
+        ref = plain.int_forward(layers, q8, pool)
+        ctl = plain.int_forward(layers, q4, pool)
         refd = ref.reshape(len(ref), -1).astype(np.int64)
         pair = np.abs(refd[:, None, :] - refd[None, :, :]).max(axis=-1)
         pair[np.eye(len(refd), dtype=bool)] = np.iinfo(np.int64).max

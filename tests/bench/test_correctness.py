@@ -178,3 +178,11 @@ def test_no_accelerator_no_result(tmp_path):
     assert proc.returncode != 0
     assert "no accelerator" in proc.stderr
     assert not proc.stdout.strip()
+
+
+def test_window_without_answers_compares_nothing():
+    """A window that served no answer has no gap to read: the check gives
+    None (so the run is not correct) and never calls the reference on an
+    empty batch."""
+    assert cell.compare(None, [], [], [], None, [], 10) == \
+        {"logit_gap_lsb": [None, 10]}

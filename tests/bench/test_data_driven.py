@@ -68,8 +68,14 @@ def test_real_cells_resolve():
     bm = spec.Benchmark(REPO)
     for name in bm.cells:
         c = bm.cell(name)
-        assert c.chips == 1
+        assert c.chips in (1, 4)
         assert any(m.name == "setup_s" for m in c.end_to_end)
         assert len(c.end_to_end) >= 2 and c.per_layer
         for m in c.per_layer:
             assert (bm.bench / "readers" / f"{m.reader}.py").exists()
+        # the reference and the work counts resolve for its architecture
+        layers = bm.arch(c.config).layers(c.config)
+        ref = bm.reference(c.config)
+        assert all(callable(getattr(ref, f))
+                   for f in ("calibrate", "quantize", "int_forward"))
+        assert bm.work(c.config).ops_per_sample(layers) > 0

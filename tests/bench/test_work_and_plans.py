@@ -107,7 +107,7 @@ def _roofline(layers, family, samples, batches, seconds):
             return [types.SimpleNamespace(dur_ns=seconds * 1e9)]
     rec = types.SimpleNamespace(
         trace=Tr(), session={"requests": samples, "batches": batches},
-        layers=layers, peak=work.peaks("TPU v5 lite"))
+        layers=layers, work=work.SHARED, peak=work.peaks("TPU v5 lite"))
     return reader.read(rec, {"family": family, "pattern": "x"})
 
 
