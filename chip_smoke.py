@@ -1,4 +1,5 @@
-"""One-chip bring-up check: MobileNetV2@112 through the normal serving path.
+"""One-chip bring-up check: MobileNetV2@112 and MobileNetV3-Large@224
+through the normal serving path.
 
 Run from the repository root on a machine with a TPU:
 
@@ -20,8 +21,16 @@ width 1.0, random weights from seed 0), and checks what comes out:
    (``tpu_custom_call``); outputs must equal, bit for bit, the same plans
    compiled with ``use_pallas=False`` on this chip and the eager
    ``SplitExecutor`` oracle (one request);
-3. float — the planner's plan served as a float Session, within
-   ``FLOAT_RTOL`` of ``reference_forward`` at ``highest`` matmul precision.
+3. MobileNetV3-Large@224 (squeeze-excite gates, hard-swish, 5x5 depthwise)
+   on the planner's spatial plan the benchmark commits
+   (``bench/configs/mnv3l_224_int8_spatial.plan.json``), served as an int8
+   tenant with buckets (1, 32): a full bucket and a single request, bit for
+   bit equal to ``use_pallas=False`` and, on two requests, to the eager
+   oracle; its lowered program calls the 5x5 kernel (``dwconv5x5_bands``);
+4. float — the planner's plan served as a float Session, within
+   ``FLOAT_RTOL`` of ``reference_forward`` at ``highest`` matmul precision
+   (last: it sets that precision for the process, and the int8 kernels
+   refuse an int8 matmul at float32 contract precision).
 
 No phase catches an error and goes on; any failure exits non-zero.  On a
 host without a TPU it exits non-zero at once: there is no CPU path.  It
@@ -42,6 +51,7 @@ ROOT = pathlib.Path(__file__).resolve().parent
 MAX_BATCH = 8
 BUCKETS = (1, MAX_BATCH)
 N_REQUESTS = 2 * MAX_BATCH          # per tenant: at least one full bucket
+V3_BATCH = 32                       # MobileNetV3-Large: one full bucket
 # Float check, as a fraction of the reference's largest |logit|.  Both sides
 # run at "highest" matmul precision (a multi-pass bf16 decomposition that is
 # about, but not bit-for-bit, f32), and the split executor sums shards and
@@ -89,7 +99,8 @@ def main() -> int:
     from repro.api import Cluster, Objective, Planner
     from repro.compile_cache import use_compile_cache
     from repro.core import SplitExecutor, reference_forward
-    from repro.models import mobilenet_v2_paper
+    from repro.api import Plan
+    from repro.models import mobilenet_v2_paper, mobilenet_v3_large
     from repro.serve import SLO, Server
 
     print(f"compile cache: {use_compile_cache()}")
@@ -157,7 +168,44 @@ def main() -> int:
     print(f"int8: request 0 bit-exact vs eager oracle "
           f"({time.perf_counter() - t0:.1f} s)")
 
-    # -- 3. float -----------------------------------------------------------
+    # -- 3. MobileNetV3-Large@224 -------------------------------------------
+    t0 = time.perf_counter()
+    v3 = mobilenet_v3_large(seed=0)
+    v3_plan = Plan.from_json(
+        ROOT / "bench" / "configs" / "mnv3l_224_int8_spatial.plan.json", v3)
+    print(f"plan mnv3l: mode={v3_plan.mode}/{v3_plan.fusion} "
+          f"workers={v3_plan.n_workers}")
+    v3_xs = rng.standard_normal((V3_BATCH + 1, *v3.input_shape)).astype(
+        np.float32)
+    v3_sess = v3_plan.compile(precision="int8", seed=0, max_batch=V3_BATCH,
+                              buckets=(1, V3_BATCH))
+    text = v3_sess.engine.lower_batch(v3_xs[:V3_BATCH], "int8").as_text()
+    if "tpu_custom_call" not in text or "dwconv5x5_bands" not in text:
+        fail("int8 mnv3l: the lowered program calls no compiled 5x5 kernel")
+    print(f"int8 mnv3l: compiled buckets {v3_sess.buckets} in "
+          f"{time.perf_counter() - t0:.1f} s")
+    v3_server = Server()
+    v3_server.add_tenant("mnv3l", v3_sess, slo=open_slo)
+    v3_out = serve(v3_server, ["mnv3l"], v3_xs[:V3_BATCH])["mnv3l"]
+    v3_one = v3_sess.run(v3_xs[V3_BATCH])
+    t0 = time.perf_counter()
+    v3_plain = v3_plan.compile(precision="int8", qmodel=v3_sess.qmodel,
+                               use_pallas=False, max_batch=V3_BATCH,
+                               buckets=(1, V3_BATCH))
+    if not (np.array_equal(v3_out, v3_plain.submit_many(v3_xs[:V3_BATCH]))
+            and np.array_equal(v3_one, v3_plain.run(v3_xs[V3_BATCH]))):
+        fail("int8 mnv3l: Pallas path != use_pallas=False path")
+    print(f"int8 mnv3l: buckets 32 and 1 bit-exact vs use_pallas=False "
+          f"({time.perf_counter() - t0:.1f} s)")
+    t0 = time.perf_counter()
+    v3_oracle = SplitExecutor(v3_plan.split, v3_sess.qmodel)
+    for i in range(2):
+        if not np.array_equal(v3_out[i], v3_oracle.run(v3_xs[i], "int8")):
+            fail(f"int8 mnv3l: request {i} != eager SplitExecutor oracle")
+    print(f"int8 mnv3l: requests 0-1 bit-exact vs eager oracle "
+          f"({time.perf_counter() - t0:.1f} s)")
+
+    # -- 4. float -----------------------------------------------------------
     with jax.default_matmul_precision("highest"):
         ref = np.stack([reference_forward(model, x) for x in xs])
     scale = float(np.abs(ref).max())

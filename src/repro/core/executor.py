@@ -38,8 +38,8 @@ Two executors share those semantics:
   and the int8 epilogue constants, then lowers the *entire* SplitPlan into a
   single ``jax.jit``-ed function per mode: only pure jnp ops inside the
   trace, no host sync until the final output.  In int8 mode the hot ops
-  route through the Pallas kernels (``kernels.dwconv`` for 3x3 depthwise,
-  ``kernels.qgemm`` for conv-as-im2col and linear shards) when
+  route through the Pallas kernels (``kernels.dwconv`` for 3x3 and 5x5
+  depthwise, ``kernels.qgemm`` for conv-as-im2col and linear shards) when
   ``use_pallas`` is enabled — on by default on TPU, with a pure-jnp fallback
   elsewhere that performs the *same float32 epilogue arithmetic*, so both
   paths (and the eager oracle) agree bit-for-bit on int8.  ``run_batch``
@@ -50,6 +50,7 @@ Two executors share those semantics:
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import hashlib
 
@@ -140,6 +141,32 @@ def _avgpool_int8(x_q, in_scale: float, out_scale: float):
     s = jnp.sum(x_q.astype(jnp.int32), axis=(-2, -1), keepdims=True)
     return jnp.clip(jnp.round(s.astype(jnp.float32) * factor),
                     -127, 127).astype(jnp.int8)
+
+
+def _gate_int8(x_q, x_scale: float, g_q, g_scale: float, out_scale: float):
+    """Coordinator-side channel gate (the multiply that closes a
+    squeeze-excite branch): the stashed activation ``x_q`` (C, H, W) times
+    the per-channel gate ``g_q`` (C, 1, 1) as an exact int32 product, then
+    one f32 multiply by ``s_x * s_g / s_out``, round and clip — the
+    multiply-only form of :func:`_avgpool_int8`, shared by both executors."""
+    factor = float(x_scale) * float(g_scale) / float(out_scale)
+    prod = x_q.astype(jnp.int32) * g_q.astype(jnp.int32)
+    return jnp.clip(jnp.round(prod.astype(jnp.float32) * factor),
+                    -127, 127).astype(jnp.int8)
+
+
+def se_paths(model) -> set[int]:
+    """Indices of the layers on a squeeze-excite branch: those after the
+    stashed activation a ``gate`` scales, up to and including the gate
+    (pool, FCs, gate multiply) — the device work the compiled executor puts
+    under ``jax.named_scope("se_gate")``."""
+    out: set[int] = set()
+    for g, layer in enumerate(model.layers):
+        if layer.kind == "gate":
+            src = max(i for i in range(g)
+                      if model.layers[i].save_as == layer.gate_of)
+            out.update(range(src + 1, g + 1))
+    return out
 
 
 def _residual_add_int8(cur_q, cur_scale: float, other_q, other_scale: float):
@@ -363,7 +390,15 @@ class SplitExecutor:
             i = idxs[-1]
             layer = model.layers[i]
             cur = cur.reshape(model.layers[idxs[0]].in_shape)
-            if self.plan.splits[idxs[0]].mode == "spatial":
+            if layer.kind == "gate":     # coordinator-side, like the pool
+                if mode == "int8":
+                    x_scale, x_q = stash[layer.gate_of]
+                    ql = self.qmodel.layers[i]
+                    cur = _gate_int8(x_q, x_scale, cur, ql.in_scale,
+                                     ql.out_scale)
+                else:
+                    cur = stash[layer.gate_of] * cur
+            elif self.plan.splits[idxs[0]].mode == "spatial":
                 cur = self._run_block_spatial(idxs, cur, mode)
             elif mode == "int8":
                 cur = self._run_layer_int8(i, layer, self.plan.splits[i], cur)
@@ -495,7 +530,7 @@ def _plan_fingerprint(plan: SplitPlan, qmodel: QuantizedModel | None) -> str:
     for lyr in plan.model.layers:
         h.update(repr((lyr.kind, lyr.in_shape, lyr.out_shape, lyr.kernel,
                        lyr.stride, lyr.padding, lyr.activation, lyr.save_as,
-                       lyr.residual_from)).encode())
+                       lyr.residual_from, lyr.gate_of)).encode())
         _arr(lyr.weight)
         _arr(lyr.bias)
     if qmodel is not None:
@@ -516,10 +551,11 @@ def _plan_fingerprint(plan: SplitPlan, qmodel: QuantizedModel | None) -> str:
 
 
 def _kernel_eligible_dwconv(layer: LayerSpec) -> bool:
-    """The Pallas dwconv kernel covers exactly MobileNet-style depthwise
-    convs: 3x3, SAME padding 1, square stride."""
-    return (layer.kind == "dwconv" and layer.kernel == (3, 3)
-            and layer.padding == (1, 1)
+    """The Pallas dwconv kernels cover exactly MobileNet-style depthwise
+    convs: 3x3 or 5x5, SAME padding k // 2, square stride."""
+    k = layer.kernel[0]
+    return (layer.kind == "dwconv" and layer.kernel in ((3, 3), (5, 5))
+            and layer.padding == (k // 2, k // 2)
             and layer.stride[0] == layer.stride[1])
 
 
@@ -572,6 +608,7 @@ class CompiledSplitExecutor:
                 if layer.save_as is not None:
                     self._save_scale[layer.save_as] = float(
                         qmodel.layers[i].out_scale)
+        self._se = se_paths(plan.model)
         self._fns: dict[str, callable] = {}
         self._batch_fns: dict[str, callable] = {}
 
@@ -814,12 +851,10 @@ class CompiledSplitExecutor:
                 i = idxs[-1]
                 layer = model.layers[i]
                 cur = cur.reshape(model.layers[idxs[0]].in_shape)
-                if self.plan.splits[idxs[0]].mode == "spatial":
-                    cur = self._block_spatial(idxs, cur, mode)
-                elif mode == "int8":
-                    cur = self._layer_int8(i, layer, self.plan.splits[i], cur)
-                else:
-                    cur = self._layer_float(i, layer, self.plan.splits[i], cur)
+                scope = (jax.named_scope("se_gate") if i in self._se
+                         else contextlib.nullcontext())
+                with scope:
+                    cur = self._group(idxs, i, layer, cur, stash, mode)
                 if layer.residual_from is not None:
                     if mode == "int8":
                         cur = _residual_add_int8(
@@ -833,6 +868,22 @@ class CompiledSplitExecutor:
             return cur
 
         return fn
+
+    def _group(self, idxs, i, layer, cur, stash, mode):
+        """One block group of the traced plan (residual bookkeeping is the
+        caller's)."""
+        if layer.kind == "gate":         # coordinator-side, like the pool
+            if mode == "int8":
+                return _gate_int8(stash[layer.gate_of],
+                                  self._save_scale[layer.gate_of], cur,
+                                  float(self.qmodel.layers[i].in_scale),
+                                  float(self.qmodel.layers[i].out_scale))
+            return stash[layer.gate_of] * cur
+        if self.plan.splits[idxs[0]].mode == "spatial":
+            return self._block_spatial(idxs, cur, mode)
+        if mode == "int8":
+            return self._layer_int8(i, layer, self.plan.splits[i], cur)
+        return self._layer_float(i, layer, self.plan.splits[i], cur)
 
     # -- compiled-executable cache ------------------------------------------
     # Jitted plan functions are shared ACROSS executor instances keyed on the
@@ -941,6 +992,8 @@ def reference_forward(model, x: np.ndarray, collect_activations: bool = False):
         cur = cur.reshape(layer.in_shape)
         if layer.kind == "avgpool":
             cur = jnp.mean(cur, axis=(1, 2), keepdims=True)
+        elif layer.kind == "gate":
+            cur = stash[layer.gate_of] * cur
         elif layer.kind == "linear":
             cur = cur.reshape(-1) @ jnp.asarray(layer.weight) + jnp.asarray(layer.bias)
             cur = cur.reshape(layer.out_shape)

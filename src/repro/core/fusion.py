@@ -125,12 +125,28 @@ def group_blocks(model) -> list[FusedBlock]:
     return blocks
 
 
+# the fused activations every executor and kernel epilogue implements
+ACTIVATIONS = (None, "relu", "relu6", "hsigmoid", "hswish")
+
+
 def apply_activation(x, activation: str | None):
-    """In-place-style fused activation (works for numpy and jax arrays)."""
+    """In-place-style fused activation (works for numpy and jax arrays).
+
+    ``hsigmoid`` is relu6(x + 3) / 6 and ``hswish`` x * hsigmoid(x)
+    (MobileNetV3), written as ``clip(x * (1/6), -1/2, 1/2) + 1/2``: the add
+    follows a clip, never a multiply, so no compiler can fuse it into an
+    FMA, and no two constant multiplies follow each other (XLA folds those
+    under jit but not op by op).  The Pallas epilogues
+    (``kernels.epilogue.activate``) compute the same steps; see
+    ``core.quantize.epilogue_params``."""
     if activation is None:
         return x
     if activation == "relu":
         return x * (x > 0)
     if activation == "relu6":
         return (x * (x > 0)).clip(max=6.0)
+    if activation == "hsigmoid":
+        return (x * (1.0 / 6.0)).clip(min=-0.5, max=0.5) + 0.5
+    if activation == "hswish":
+        return x * ((x * (1.0 / 6.0)).clip(min=-0.5, max=0.5) + 0.5)
     raise ValueError(f"unknown activation {activation!r}")

@@ -14,6 +14,12 @@ once per worker that holds them (halo duplication).  For layers inside a
 fused block the window is produced locally rather than routed, but it is
 resident worker RAM all the same, and the weight term is the *full* layer
 (spatial mode replicates weights instead of splitting them).
+
+Coordinator-side layers — the global pool and the squeeze-excite ``gate`` —
+hold no worker position, so they add nothing here: the coordinator (a PC in
+the paper's testbed) holds the assembled tensor they read and every stash
+(``save_as``: residual sources and the activation a gate scales), outside
+the per-worker budget the planner gates.
 """
 from __future__ import annotations
 

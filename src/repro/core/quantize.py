@@ -99,6 +99,19 @@ def epilogue_params(ql: QuantizedLayer) -> tuple[np.ndarray, np.ndarray]:
     ``a*b + c`` into an FMA inside large fused graphs (jit) but not in
     op-by-op dispatch, which flips requantization rounding at ties.  With
     multiplies only, eager and jitted execution round identically.
+
+    Two more rules keep that true for the activations.  An add is allowed
+    only where its operand is not a product: the hard sigmoid's ``+ 3`` is
+    written ``clip(y * (1/6), -1/2, 1/2) + 1/2`` (``fusion.apply_activation``),
+    so the add follows a clip.  And no two multiplies by constants may
+    follow each other: XLA folds ``(y * c1) * c2`` into ``y * (c1 * c2)``
+    under jit but not op by op, so the 1/6 sits inside the clip, where a
+    non-constant (the clip) separates it from ``1 / out_scale``.
+
+    The channel gate (``gate``, coordinator-side) keeps to the same form as
+    the pool and the residual add: the exact int32 product of the stashed
+    activation and the gate, one f32 multiply by ``s_x * s_g / s_out``, round
+    and clip (``executor._gate_int8``).
     """
     m = (ql.in_scale * ql.w_scale).astype(np.float32)
     return m, ql.b_q.astype(np.int32)

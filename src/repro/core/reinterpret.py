@@ -5,7 +5,7 @@ fine-grained splitting needs *neuron-level* dependencies.  This module defines
 the internal representation a pre-trained model is "reinterpreted" into:
 
   * :class:`LayerSpec` — one entry per fused computation (conv/dwconv/linear/
-    pool) carrying tensor dimensions, kernel parameters and the weight tensors
+    pool/channel gate) carrying tensor dimensions, kernel parameters and the weight tensors
     themselves (the paper serializes the same metadata from its Rust tracer).
   * receptive-field queries — for any output neuron ``(c, h, w)`` of a layer,
     the exact set of input activations required to compute it (paper Fig. 3,
@@ -36,6 +36,10 @@ class LayerSpec:
       * ``linear``  — fully connected, weight ``(in_features, out_features)``
                       (column ``j`` == output neuron ``j``, paper Alg. 2)
       * ``avgpool`` — global average pool (no weights; coordinator-side)
+      * ``gate``    — channel gate (no weights; coordinator-side): the
+                      stashed activation ``gate_of`` (C, H, W) times this
+                      layer's input, a (C, 1, 1) per-channel factor — the
+                      multiply that closes a squeeze-excite branch
     """
 
     name: str
@@ -47,11 +51,13 @@ class LayerSpec:
     stride: tuple[int, int] = (1, 1)
     padding: tuple[int, int] = (0, 0)
     kernel: tuple[int, int] = (1, 1)
-    activation: str | None = None      # None | "relu" | "relu6" (fused, §V.D)
+    # None | "relu" | "relu6" | "hswish" | "hsigmoid" (fused, §V.D)
+    activation: str | None = None
     # Residual bookkeeping: coordinator-side (the coordinator "prepares the
     # input activations for the next layer", Alg. 4 line 9 — adds happen there).
     save_as: str | None = None         # stash this layer's output under a key
     residual_from: str | None = None   # add stashed activation to this output
+    gate_of: str | None = None         # gate: the stashed activation it scales
 
     def __post_init__(self) -> None:
         if self.kind in ("conv", "dwconv") and self.weight is not None:
@@ -85,6 +91,8 @@ class LayerSpec:
             return range(ci), range(1), range(1)
         if self.kind == "avgpool":
             return range(c, c + 1), range(hi), range(wi)
+        if self.kind == "gate":     # (the stash is read at the coordinator)
+            return range(c, c + 1), range(1), range(1)
         kh, kw = self.kernel
         sh, sw = self.stride
         ph, pw = self.padding
@@ -108,7 +116,7 @@ class LayerSpec:
         rows [h_lo, h_hi] (inclusive).  Vectorized form of receptive_field
         used by the scalable mapping path."""
         _, hi, _ = self.in_shape
-        if self.kind in ("linear", "avgpool"):
+        if self.kind in ("linear", "avgpool", "gate"):
             return 0, hi
         kh, _ = self.kernel
         sh, _ = self.stride
@@ -119,7 +127,7 @@ class LayerSpec:
 
     def input_cols_for_output_cols(self, w_lo: int, w_hi: int) -> tuple[int, int]:
         _, _, wi = self.in_shape
-        if self.kind in ("linear", "avgpool"):
+        if self.kind in ("linear", "avgpool", "gate"):
             return 0, wi
         _, kw = self.kernel
         _, sw = self.stride
@@ -172,6 +180,11 @@ def layer_macs(layer: LayerSpec) -> int:
         return layer.in_shape[0] * c
     if layer.kind == "avgpool":
         return layer.n_in
+    if layer.kind == "gate":
+        return layer.n_out
+    if layer.kind not in ("conv", "dwconv"):
+        raise ValueError(f"layer {layer.name}: no MAC count for op kind "
+                         f"{layer.kind!r}")
     kh, kw = layer.kernel
     cin = 1 if layer.kind == "dwconv" else layer.in_shape[0]
     return c * h * w * kh * kw * cin
@@ -193,13 +206,16 @@ def trace_sequential(spec: Sequence[dict], input_shape: Shape3,
     """Build a ReinterpretedModel from a declarative op list.
 
     Each dict: {kind, out_channels?, kernel?, stride?, padding?, features?,
-    activation?, save_as?, residual_from?}.  Weights are taken from 'weight'/
+    activation?, save_as?, residual_from?, gate_of?}.  A ``gate`` takes the
+    (C, 1, 1) output of the op before it and names in ``gate_of`` the
+    ``save_as`` key of the (C, H, W) activation it scales.  Weights are taken from 'weight'/
     'bias' keys if present, else randomly initialized (He) via ``rng`` —
     mirrors the paper's offline trace of a pre-trained network.
     """
     rng = rng or np.random.default_rng(0)
     layers: list[LayerSpec] = []
     cur = tuple(input_shape)
+    stashed: dict[str, Shape3] = {}
     for i, op in enumerate(spec):
         kind = op["kind"]
         name = op.get("name", f"L{i}_{kind}")
@@ -258,10 +274,25 @@ def trace_sequential(spec: Sequence[dict], input_shape: Shape3,
         elif kind == "avgpool":
             layers.append(LayerSpec(name, "avgpool", cur, (cur[0], 1, 1)))
             cur = (cur[0], 1, 1)
+        elif kind == "gate":
+            key = op["gate_of"]
+            if key not in stashed:
+                raise ValueError(f"{name}: gate_of {key!r} names no earlier "
+                                 "save_as")
+            target = stashed[key]
+            if cur != (target[0], 1, 1):
+                raise ValueError(f"{name}: gate input {cur} does not give one "
+                                 f"factor per channel of {key!r} {target}")
+            layers.append(LayerSpec(name, "gate", cur, target, gate_of=key,
+                                    save_as=op.get("save_as"),
+                                    residual_from=op.get("residual_from")))
+            cur = target
         elif kind == "flatten":
             # Flatten is implicit: CHW row-major flat order is preserved, so a
             # downstream linear simply declares in_shape (C*H*W, 1, 1).
             cur = (cur[0] * cur[1] * cur[2], 1, 1)
         else:
             raise ValueError(f"unknown op kind {kind!r}")
+        if layers and layers[-1].save_as is not None:
+            stashed[layers[-1].save_as] = layers[-1].out_shape
     return ReinterpretedModel(layers=list(layers), input_shape=tuple(input_shape))

@@ -338,6 +338,12 @@ def _boundary_deps(prev_split, split, up_bytes: np.ndarray) -> list[list[int]]:
     every producer, so they (and mixed boundaries) wait on every producer
     that uploads anything — the per-boundary barrier the serial model also
     implies.
+
+    A coordinator-side producer (the pool, or a squeeze-excite ``gate``)
+    uploads nothing, so its consumers list no producer here; the barrier
+    still holds in :func:`_pipelined_timeline`, whose downlink FIFO starts
+    the consumer's download no earlier than the coordinator-side segment's,
+    which waited on every upload before it.
     """
     n = len(split.shards)
     # producers are enumerated over the *producer* split's width (up_bytes

@@ -1,9 +1,10 @@
-"""Pallas TPU kernel: int8 3x3 depthwise convolution with fused folded-BN +
-ReLU6 + requantization (MobileNetV2's hot-spot op, §VI).
+"""Pallas TPU kernel: int8 k x k depthwise convolution (k = 3 or 5) with
+fused folded-BN + activation + requantization (MobileNetV2's hot-spot op,
+§VI; MobileNetV3 adds the 5x5 layers).
 
 Depthwise conv has no reduction over channels, so it is VPU (not MXU) work:
-9 shifted element-wise products accumulated in int32, then a multiply-only
-f32 epilogue.  The kernel works **channels-last**: the wrapper transposes the
+k*k shifted element-wise products accumulated in int32, then a
+multiply-only f32 epilogue (``kernels.epilogue``).  The kernel works **channels-last**: the wrapper transposes the
 (N, C, R, W) band stack to (N, R, W, C), so channels fill the 128-lane axis
 and the map width the sublanes, and transposes the int8 output back.  The
 callers (the executor is CHW) see the same shapes in and out.
@@ -28,12 +29,15 @@ them:
   32-bit values: a row chunk is widened to int32 once, then windowed.
 
 :func:`dwconv3x3_bands` takes a stack of spatial band windows
-(bands, C, R, W+2).  Under ``jax.vmap`` (the executor's ``run_batch``) a
+(bands, C, R, W+2), :func:`dwconv5x5_bands` of (bands, C, R, W+4): one
+kernel body, the tap count a static parameter, each size behind its own
+jitted entry so its device events carry its name.  Under ``jax.vmap`` (the executor's ``run_batch``) a
 ``custom_vmap`` rule folds the batch into the stack, so B samples × bands
 reach the kernel as one stack, not as a grid axis with a block of one
 sample.  Rows beyond a band's valid window are zero-filled by the caller and
 their outputs discarded, so heterogeneous band heights ride one uniform
-stack.  :func:`dwconv3x3` is the one-sample case (a stack of one band).
+stack.  :func:`dwconv3x3` and :func:`dwconv5x5` are the one-sample cases (a
+stack of one band).
 """
 from __future__ import annotations
 
@@ -45,6 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..backend import resolve_interpret
+from ..epilogue import epilogue
 
 # Estimated VMEM of one grid step (double-buffered blocks plus the row
 # chunk's temporaries), kept well under v5e's 16 MiB scoped VMEM.
@@ -70,15 +75,16 @@ class Geometry(NamedTuple):
     phases: int   # stride**2 row/column phases of the input
     hh: int       # rows of one phase
     ww: int       # cols of one phase
+    k: int = 3    # taps along each axis
 
 
-def geometry(rows: int, cols: int, stride: int) -> Geometry:
-    oh = (rows - 3) // stride + 1
-    ow = (cols - 3) // stride + 1
+def geometry(rows: int, cols: int, stride: int, k: int = 3) -> Geometry:
+    oh = (rows - k) // stride + 1
+    ow = (cols - k) // stride + 1
     if stride == 1:
-        return Geometry(oh, ow, 1, rows, cols)
-    return Geometry(oh, ow, stride * stride, oh + 2 // stride,
-                    ow + 2 // stride)
+        return Geometry(oh, ow, 1, rows, cols, k)
+    return Geometry(oh, ow, stride * stride, oh + (k - 1) // stride,
+                    ow + (k - 1) // stride, k)
 
 
 class Blocks(NamedTuple):
@@ -93,10 +99,11 @@ def step_vmem_bytes(stack: int, channels: int, rows: int, g: Geometry) -> int:
     x = stack * g.phases * _tile_bytes(g.hh, g.ww, channels, 1)
     out = stack * max(_tile_bytes(g.oh, g.ow, channels, 1),
                       _tile_bytes(g.oh, g.ow, channels, 4))
-    per_channel = 11 * _tile_bytes(1, 1, channels, 4)
-    slab = _tile_bytes(rows + 2, g.ww, channels, 4)
+    per_channel = (g.k * g.k + 2) * _tile_bytes(1, 1, channels, 4)
+    slab = _tile_bytes(rows + g.k - 1, g.ww, channels, 4)
     # widened phases, their column shifts, the accumulator and epilogue
-    temps = 3 * g.phases * slab + 4 * _tile_bytes(rows, g.ow, channels, 4)
+    temps = (g.k * g.phases * slab
+             + 4 * _tile_bytes(rows, g.ow, channels, 4))
     return 2 * (x + out + per_channel) + temps
 
 
@@ -105,62 +112,44 @@ def _divisors(n: int) -> list[int]:
 
 
 def dwconv_blocks(n: int, rows: int, cols: int, c: int,
-                  stride: int) -> Blocks:
+                  stride: int, k: int = 3) -> Blocks:
     """The blocks for a stack of ``n`` windows of (c, rows, cols): the
-    widest channel block (all of C, else a multiple of 128) whose step fits
-    :data:`VMEM_BUDGET`, the largest divisor of the output rows whose
+    widest channel block (all of C, else the multiple of 128 that pads C the
+    least) whose step fits :data:`VMEM_BUDGET`, the largest divisor of the output rows whose
     widened input chunk spans at most :data:`CHUNK_VREGS` vregs, and the
     largest divisor of ``n`` whose step fits the budget.  A shape no block
     fits gets the smallest blocks, and Mosaic reports what does not fit."""
-    g = geometry(rows, cols, stride)
-    widths = [c, *range(128 * (_cdiv(c, 128) - 1), 0, -128)]
+    g = geometry(rows, cols, stride, k)
+    # below all of C, the blocks that pad C least come first, widest first
+    narrow = sorted(range(128, 128 * _cdiv(c, 128), 128),
+                    key=lambda b: (_cdiv(c, b) * b, -b))
+    widths = [c, *narrow]
     cb = next((k for k in widths
                if step_vmem_bytes(1, k, 1, g) <= VMEM_BUDGET), min(c, 128))
     per_row = _cdiv(g.ww, 8) * _cdiv(cb, 128)
     rb = max(r for r in _divisors(g.oh)
-             if r == 1 or (r + 2) * per_row <= CHUNK_VREGS)
+             if r == 1 or (r + k - 1) * per_row <= CHUNK_VREGS)
     nb = max(d for d in _divisors(n)
              if d == 1 or step_vmem_bytes(d, cb, rb, g) <= VMEM_BUDGET)
     return Blocks(nb, cb, rb)
 
 
-def _epilogue(acc, scale, bias, *, activation: str | None,
-              out_scale: float | None, int_bias: bool, out_dtype):
-    """Fused folded-BN + activation + requantization epilogue on an int32
-    accumulator (scale/bias broadcast along its last, channel, axis)."""
-    if int_bias:
-        # b_q added in exact int32; float steps are multiplies only so the
-        # result is bit-identical to the executors' jnp epilogue (no
-        # FMA-contraction sensitivity — see core.quantize).
-        y = (acc + bias).astype(jnp.float32) * scale
-    else:
-        y = acc.astype(jnp.float32) * scale + bias
-    if activation == "relu":
-        y = jnp.maximum(y, 0.0)
-    elif activation == "relu6":
-        y = jnp.clip(y, 0.0, 6.0)
-    if out_scale is not None:
-        return jnp.clip(jnp.round(y * (1.0 / out_scale)),
-                        -127, 127).astype(jnp.int8)
-    return y.astype(out_dtype)
-
-
-def _dwconv_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, *, stride: int,
-                   rows: int, activation: str | None,
+def _dwconv_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, *, k: int,
+                   stride: int, rows: int, activation: str | None,
                    out_scale: float | None, int_bias: bool):
-    # x_ref: (nb, s*s, hh, ww, cb) int8 phases; w_ref: (9, 1, cb) int32;
+    # x_ref: (nb, s*s, hh, ww, cb) int8 phases; w_ref: (k*k, 1, cb) int32;
     # scale_ref/bias_ref: (1, cb); o_ref: (nb, oh, ow, cb)
     nb, oh, ow, _ = o_ref.shape
-    extra = 2 // stride         # input rows a chunk needs beyond its own
-    taps = [w_ref[k] for k in range(9)]
+    extra = (k - 1) // stride   # input rows a chunk needs beyond its own
+    taps = [w_ref[t] for t in range(k * k)]
     scale, bias = scale_ref[...], bias_ref[...]
 
     def chunk(t, carry):
         n, r0 = t // (oh // rows), (t % (oh // rows)) * rows
         slabs = {}
         acc = None
-        for i in range(3):
-            for j in range(3):
+        for i in range(k):
+            for j in range(k):
                 p, q, a, b = i % stride, j % stride, i // stride, j // stride
                 if (p, q) not in slabs:
                     slabs[p, q] = x_ref[n, p * stride + q,
@@ -168,9 +157,9 @@ def _dwconv_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, *, stride: int,
                                         ].astype(jnp.int32)
                 if (p, q, b) not in slabs:
                     slabs[p, q, b] = slabs[p, q][:, b:b + ow]
-                term = slabs[p, q, b][a:a + rows] * taps[3 * i + j]
+                term = slabs[p, q, b][a:a + rows] * taps[k * i + j]
                 acc = term if acc is None else acc + term
-        o_ref[n, pl.ds(r0, rows)] = _epilogue(
+        o_ref[n, pl.ds(r0, rows)] = epilogue(
             acc, scale, bias, activation=activation, out_scale=out_scale,
             int_bias=int_bias, out_dtype=o_ref.dtype)
         return carry
@@ -195,13 +184,15 @@ def _channels_last_phases(x, stride: int, g: Geometry):
 def _dwconv_stack(x_win, w, scale, bias, *, stride: int,
                   activation: str | None, out_scale: float | None,
                   interpret: bool):
-    """The kernel on one (N, C, R, Wp) stack; returns (N, C, oh, ow)."""
+    """The kernel on one (N, C, R, Wp) stack and (C, k, k) taps; returns
+    (N, C, oh, ow)."""
     n, c, rp, wp = x_win.shape
-    g = geometry(rp, wp, stride)
-    blk = dwconv_blocks(n, rp, wp, c, stride)
+    k = w.shape[-1]
+    g = geometry(rp, wp, stride, k)
+    blk = dwconv_blocks(n, rp, wp, c, stride, k)
     cp = _cdiv(c, blk.channels) * blk.channels
     phases = _channels_last_phases(x_win, stride, g)
-    taps = w.reshape(c, 9).T.astype(jnp.int32)[:, None]
+    taps = w.reshape(c, k * k).T.astype(jnp.int32)[:, None]
     scale, bias = scale.reshape(1, c), jnp.asarray(bias).reshape(1, c)
     if cp != c:
         pad = ((0, 0),) * 4 + ((0, cp - c),)
@@ -210,7 +201,8 @@ def _dwconv_stack(x_win, w, scale, bias, *, stride: int,
                              for a in (taps, scale, bias))
     int_bias = jnp.issubdtype(bias.dtype, jnp.integer)
     out_dtype = jnp.int8 if out_scale is not None else jnp.float32
-    kernel = functools.partial(_dwconv_kernel, stride=stride, rows=blk.rows,
+    kernel = functools.partial(_dwconv_kernel, k=k, stride=stride,
+                               rows=blk.rows,
                                activation=activation, out_scale=out_scale,
                                int_bias=int_bias)
     nb, cb = blk.stack, blk.channels
@@ -221,7 +213,7 @@ def _dwconv_stack(x_win, w, scale, bias, *, stride: int,
         in_specs=[
             pl.BlockSpec((nb, g.phases, g.hh, g.ww, cb),
                          lambda si, ci: (si, 0, 0, 0, ci)),
-            pl.BlockSpec((9, 1, cb), lambda si, ci: (0, 0, ci)),
+            pl.BlockSpec((k * k, 1, cb), lambda si, ci: (0, 0, ci)),
             per_channel,
             per_channel,
         ],
@@ -250,8 +242,18 @@ def _stack_folding(fn):
     return folded
 
 
-@functools.partial(jax.jit, static_argnames=("stride", "activation",
-                                             "out_scale", "interpret"))
+def _bands(x_win, w, scale, bias, *, stride, activation, out_scale,
+           interpret):
+    fn = functools.partial(_dwconv_stack, stride=stride,
+                           activation=activation, out_scale=out_scale,
+                           interpret=resolve_interpret(interpret))
+    return _stack_folding(fn)(x_win, w, scale, bias)
+
+
+_STATIC = ("stride", "activation", "out_scale", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def dwconv3x3_bands(x_win, w, scale, bias, *, stride: int = 1,
                     activation: str | None = None,
                     out_scale: float | None = None,
@@ -271,10 +273,22 @@ def dwconv3x3_bands(x_win, w, scale, bias, *, stride: int = 1,
     (requantized at ``out_scale``) or f32.  ``interpret=None`` auto-detects:
     compiled on TPU, interpret elsewhere.
     """
-    fn = functools.partial(_dwconv_stack, stride=stride,
-                           activation=activation, out_scale=out_scale,
-                           interpret=resolve_interpret(interpret))
-    return _stack_folding(fn)(x_win, w, scale, bias)
+    return _bands(x_win, w, scale, bias, stride=stride,
+                  activation=activation, out_scale=out_scale,
+                  interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def dwconv5x5_bands(x_win, w, scale, bias, *, stride: int = 1,
+                    activation: str | None = None,
+                    out_scale: float | None = None,
+                    interpret: bool | None = None):
+    """:func:`dwconv3x3_bands` for 5x5 taps: ``x_win`` is (bands, C, R,
+    W+4), ``w`` (C, 5, 5).  Same kernel body and contract."""
+    return _bands(x_win, w, scale, bias, stride=stride,
+                  activation=activation, out_scale=out_scale,
+                  interpret=interpret)
+
 
 
 def dwconv3x3(x_pad, w, scale, bias, *, stride: int = 1,
@@ -285,3 +299,18 @@ def dwconv3x3(x_pad, w, scale, bias, *, stride: int = 1,
     return dwconv3x3_bands(x_pad[None], w, scale, bias, stride=stride,
                            activation=activation, out_scale=out_scale,
                            interpret=interpret)[0]
+
+
+def dwconv5x5(x_pad, w, scale, bias, *, stride: int = 1,
+              activation: str | None = None, out_scale: float | None = None,
+              interpret: bool | None = None):
+    """One sample: ``x_pad`` is (C, H+4, W+4) int8 (pre-padded by 2); same
+    contract as :func:`dwconv5x5_bands` otherwise.  Returns (C, oh, ow)."""
+    return dwconv5x5_bands(x_pad[None], w, scale, bias, stride=stride,
+                           activation=activation, out_scale=out_scale,
+                           interpret=interpret)[0]
+
+
+# the entry points by tap count, for callers that hold a (C, k, k) weight
+BANDS = {3: dwconv3x3_bands, 5: dwconv5x5_bands}
+SAMPLE = {3: dwconv3x3, 5: dwconv5x5}

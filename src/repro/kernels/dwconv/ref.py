@@ -1,15 +1,18 @@
-"""Pure-jnp oracle for the int8 3x3 depthwise conv kernel."""
+"""Pure-jnp oracle for the int8 depthwise conv kernel (3x3 or 5x5)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ..epilogue import epilogue
 
-def dwconv3x3_ref(x_pad, w, scale, bias, *, stride: int = 1,
-                  activation: str | None = None,
-                  out_scale: float | None = None):
-    """x_pad: (C, H+2, W+2) int8 pre-padded; w: (C, 3, 3) int8; bias: (C,)
-    f32 (real-domain) or int32 (``b_q``, added to the int32 accumulator)."""
+
+def dwconv_valid_ref(x_pad, w, scale, bias, *, stride: int = 1,
+               activation: str | None = None,
+               out_scale: float | None = None):
+    """x_pad: (C, H + k - 1, W + k - 1) int8 pre-padded; w: (C, k, k) int8;
+    bias: (C,) f32 (real-domain) or int32 (``b_q``, added to the int32
+    accumulator)."""
     lhs = x_pad[None].astype(jnp.int32)
     rhs = w[:, None].astype(jnp.int32)
     acc = jax.lax.conv_general_dilated(
@@ -17,15 +20,9 @@ def dwconv3x3_ref(x_pad, w, scale, bias, *, stride: int = 1,
         dimension_numbers=("NCHW", "OIHW", "NCHW"),
         feature_group_count=x_pad.shape[0],
         preferred_element_type=jnp.int32)[0]
-    if jnp.issubdtype(jnp.asarray(bias).dtype, jnp.integer):
-        y = (acc + bias[:, None, None]).astype(jnp.float32) * scale[:, None, None]
-    else:
-        y = acc.astype(jnp.float32) * scale[:, None, None] + bias[:, None, None]
-    if activation == "relu":
-        y = jnp.maximum(y, 0.0)
-    elif activation == "relu6":
-        y = jnp.clip(y, 0.0, 6.0)
-    if out_scale is not None:
-        return jnp.clip(jnp.round(y * (1.0 / out_scale)),
-                        -127, 127).astype(jnp.int8)
-    return y
+    bias = jnp.asarray(bias)
+    return epilogue(acc, scale[:, None, None], bias[:, None, None],
+                    activation=activation, out_scale=out_scale,
+                    int_bias=jnp.issubdtype(bias.dtype, jnp.integer),
+                    out_dtype=jnp.float32)
+

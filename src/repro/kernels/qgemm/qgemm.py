@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..backend import resolve_interpret
+from ..epilogue import epilogue
 
 
 def _qgemm_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, acc_ref,
@@ -37,24 +38,9 @@ def _qgemm_kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, acc_ref,
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _epilogue():
-        if int_bias:
-            # b_q added in exact int32; float steps are multiplies only so
-            # the result is bit-identical to the executors' jnp epilogue
-            # (no FMA-contraction sensitivity — see core.quantize).
-            acc = acc_ref[...] + bias_ref[...]
-            y = acc.astype(jnp.float32) * scale_ref[...]
-        else:
-            acc = acc_ref[...].astype(jnp.float32)
-            y = acc * scale_ref[...] + bias_ref[...]
-        if activation == "relu":
-            y = jnp.maximum(y, 0.0)
-        elif activation == "relu6":
-            y = jnp.clip(y, 0.0, 6.0)
-        if out_scale is not None:
-            y = jnp.clip(jnp.round(y * (1.0 / out_scale)), -127, 127)
-            o_ref[...] = y.astype(jnp.int8)
-        else:
-            o_ref[...] = y.astype(o_ref.dtype)
+        o_ref[...] = epilogue(acc_ref[...], scale_ref, bias_ref,
+                              activation=activation, out_scale=out_scale,
+                              int_bias=int_bias, out_dtype=o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("activation", "out_scale",

@@ -3,21 +3,16 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ..epilogue import epilogue
+
 
 def qgemm_ref(x_q, w_q, scale, bias, *, activation: str | None = None,
               out_scale: float | None = None):
     """x_q: (M, K) int8; w_q: (K, N) int8; scale: (N,) f32; bias: (N,) f32
     (real-domain) or int32 (``b_q``, added to the int32 accumulator)."""
     acc = jnp.dot(x_q.astype(jnp.int32), w_q.astype(jnp.int32))
-    if jnp.issubdtype(jnp.asarray(bias).dtype, jnp.integer):
-        y = (acc + bias[None, :]).astype(jnp.float32) * scale[None, :]
-    else:
-        y = acc.astype(jnp.float32) * scale[None, :] + bias[None, :]
-    if activation == "relu":
-        y = jnp.maximum(y, 0.0)
-    elif activation == "relu6":
-        y = jnp.clip(y, 0.0, 6.0)
-    if out_scale is not None:
-        return jnp.clip(jnp.round(y * (1.0 / out_scale)),
-                        -127, 127).astype(jnp.int8)
-    return y
+    bias = jnp.asarray(bias)
+    return epilogue(acc, scale[None, :], bias[None, :], activation=activation,
+                    out_scale=out_scale,
+                    int_bias=jnp.issubdtype(bias.dtype, jnp.integer),
+                    out_dtype=jnp.float32)

@@ -41,13 +41,35 @@ import time
 import numpy as np
 
 from ..core.executor import _conv_chw
-from ..core.fusion import apply_activation
+from ..core.fusion import ACTIVATIONS, apply_activation
 from ..core.mapping import compile_shard_geometry
 from ..core.quantize import QuantizedModel, epilogue_params, requantize
 from ..core.simulator import _segments, pipelined_dependencies
 from ..core.splitting import SplitPlan, spatial_band_geometry
 
 PRECISIONS = ("int8", "float")
+# what a worker segment and the coordinator know how to run
+KINDS = ("conv", "dwconv", "linear", "avgpool")
+
+
+def _check_activation(activation, where: str) -> None:
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"{where}: the distributed runtime does not "
+                         f"implement activation {activation!r} (it "
+                         f"implements {ACTIVATIONS})")
+
+
+def check_runnable(model) -> None:
+    """Refuse a model the runtime would run wrong: segments run every kind
+    but ``linear`` and ``avgpool`` as a conv, so a channel ``gate`` (or any
+    other kind) would be served silently wrong, as would an activation no
+    epilogue implements."""
+    for layer in model.layers:
+        if layer.kind not in KINDS:
+            raise ValueError(
+                f"layer {layer.name}: the distributed runtime does not run "
+                f"op kind {layer.kind!r} (it runs {KINDS})")
+        _check_activation(layer.activation, f"layer {layer.name}")
 
 
 def _check_precision(precision: str) -> bool:
@@ -144,6 +166,7 @@ def build_worker_setup(split: SplitPlan, qmodel: QuantizedModel | None,
     if int8 and qmodel is None:
         raise ValueError("precision='int8' requires a QuantizedModel")
     model = split.model
+    check_runnable(model)
     segments: list[dict] = []
     arrays: dict[str, np.ndarray] = {}
     for gi, idxs in enumerate(split.block_groups):
@@ -303,6 +326,9 @@ def build_segment_fns(meta: dict, arrays: dict[str, np.ndarray],
         if spec["kind"] == "skip":
             continue
         gi = spec["gi"]
+        for st in spec.get("stages", [spec]):
+            if not st.get("empty"):
+                _check_activation(st.get("activation"), f"segment {gi}")
         fp = spec.get("fingerprint")
         if cache is not None and fp is not None and fp in cache:
             cache.move_to_end(fp)
@@ -454,6 +480,7 @@ def build_coordinator_plan(split: SplitPlan, qmodel: QuantizedModel | None,
     if int8 and qmodel is None:
         raise ValueError("precision='int8' requires a QuantizedModel")
     model = split.model
+    check_runnable(model)
     groups: list[GroupPlan] = []
     segs = _segments(split)
     assert list(segs) == list(split.block_groups), \

@@ -10,7 +10,7 @@ import pytest
 from repro.kernels.decode_attn.ops import flash_decode, flash_decode_ref
 from repro.kernels.dwconv.dwconv import dwconv3x3_bands, dwconv_blocks
 from repro.kernels.dwconv.ops import dwconv, dwconv_bands, dwconv_ref
-from repro.kernels.dwconv.ref import dwconv3x3_ref
+from repro.kernels.dwconv.ref import dwconv_valid_ref
 from repro.kernels.qgemm.ops import (qconv2d, qconv2d_ref, qgemm_padded)
 from repro.kernels.qgemm.ref import qgemm_ref
 
@@ -136,8 +136,8 @@ class TestDWConv:
         got = np.asarray(dwconv_bands(x, w, s, b, stride=stride,
                                       activation="relu6", out_scale=0.05))
         for i in range(bands):
-            exp = dwconv3x3_ref(x[i], w, s, b, stride=stride,
-                                activation="relu6", out_scale=0.05)
+            exp = dwconv_valid_ref(x[i], w, s, b, stride=stride,
+                                   activation="relu6", out_scale=0.05)
             np.testing.assert_array_equal(got[i], np.asarray(exp))
 
     # (batch, bands, C, rows, cols, stride, bias): run_batch vmaps the
@@ -173,7 +173,7 @@ class TestDWConv:
         got = np.asarray(fn(x, w, s, b))
         for i in range(batch):
             for j in range(bands):
-                exp = np.asarray(dwconv3x3_ref(
+                exp = np.asarray(dwconv_valid_ref(
                     x[i, j], w, s, b, stride=stride, activation="relu6",
                     out_scale=0.05))
                 if bias == "int":
@@ -198,8 +198,8 @@ class TestDWConv:
         got = np.asarray(dwconv3x3_bands(x, w, s, b, stride=stride,
                                          activation="relu6", out_scale=0.05))
         for i in range(bands):
-            exp = dwconv3x3_ref(x[i], w, s, b, stride=stride,
-                                activation="relu6", out_scale=0.05)
+            exp = dwconv_valid_ref(x[i], w, s, b, stride=stride,
+                                   activation="relu6", out_scale=0.05)
             np.testing.assert_array_equal(got[i], np.asarray(exp))
 
     @pytest.mark.parametrize("batch,bands,c,rows,cols,stride", [
@@ -236,8 +236,8 @@ class TestDWConv:
         for i in range(2):
             for k in range(3):
                 for j in range(2):
-                    exp = dwconv3x3_ref(x[i, k, j], w[i], s, b,
-                                        activation="relu6", out_scale=0.05)
+                    exp = dwconv_valid_ref(x[i, k, j], w[i], s, b,
+                                           activation="relu6", out_scale=0.05)
                     np.testing.assert_array_equal(got[i, k, j],
                                                   np.asarray(exp))
 

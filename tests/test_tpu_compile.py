@@ -5,7 +5,8 @@ see what the TPU's kernel compiler (Mosaic) refuses: block shapes that do
 not tile, in-kernel reshapes, strided slices of loaded values.  The TPU
 compiler is installed even where no chip is attached, and compiles for a
 chip that is only described.  These tests compile the int8 kernels at every
-MobileNetV2@112 shape the served path uses, plus one whole int8
+MobileNetV2@112 shape the served path uses, every depthwise band stack of
+the committed MobileNetV3-Large@224 plan (3x3 and 5x5), plus one whole int8
 ``run_batch`` program, and check that each lowers to a compiled kernel
 (``tpu_custom_call``).  Nothing runs, so results are checked elsewhere.
 
@@ -23,11 +24,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.api import Plan
 from repro.core import CompiledSplitExecutor, quantize_model, split_model
-from repro.kernels.dwconv.dwconv import (VMEM_BUDGET, dwconv3x3,
+from repro.kernels.dwconv.dwconv import (BANDS, VMEM_BUDGET, dwconv3x3,
                                          dwconv3x3_bands, dwconv_blocks,
                                          geometry, step_vmem_bytes)
 from repro.kernels.qgemm.ops import qgemm_padded
-from repro.models import mobilenet_v2_paper
+from repro.models import mobilenet_v2_paper, mobilenet_v3_large
 
 # (channels, input rows = cols, stride) of every MobileNetV2@112 depthwise
 # layer; test_depthwise_shapes_match_model keeps the list honest
@@ -45,6 +46,10 @@ PLANS = {"spatial": ("mnv2_112_int8_spatial.plan.json", 8),
 # 8-channel, one-sample, one-band blocks)
 MAX_STEPS_PER_CALL = 32
 MAX_STEPS_PER_DISPATCH = {"spatial": 100, "neuron": 500}
+MNV3L_PLAN = ("mnv3l_224_int8_spatial.plan.json", 32)
+# at 224x224 one band of the 112x112 maps fills a step's VMEM budget alone,
+# so a call at bucket 32 takes up to 32 x 7 steps
+MNV3L_MAX_STEPS = {"call": 256, "dispatch": 768}
 
 
 @pytest.fixture(scope="module")
@@ -218,3 +223,60 @@ def test_int8_run_batch_compiles(one_chip, model):
     x = _spec(one_chip, (2, *model.input_shape), jnp.float32)
     text = ex.lower_batch(x, "int8").compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def mnv3l_dwconv_stacks():
+    """(stack, C, rows, cols, stride, k) of every depthwise band stack the
+    executor builds for one sample of the committed MobileNetV3-Large@224
+    plan (spatial, one band stack per layer)."""
+    model = mobilenet_v3_large()
+    plan = Plan.from_json(CONFIGS / MNV3L_PLAN[0], model).split
+    ex = CompiledSplitExecutor(plan)
+    stacks = []
+    for idxs in plan.block_groups:
+        if plan.splits[idxs[0]].mode != "spatial":
+            continue
+        for st in ex._banded_block(tuple(idxs)).stages:
+            layer = model.layers[st.index]
+            if layer.kind == "dwconv":
+                k = layer.kernel[0]
+                stacks.append((st.src_rows.shape[0], layer.in_shape[0],
+                               st.src_rows.shape[1],
+                               layer.in_shape[2] + k - 1, layer.stride[0], k))
+    return stacks
+
+
+def test_mnv3l_plan_bands_every_depthwise_layer():
+    stacks = mnv3l_dwconv_stacks()
+    assert len(stacks) == 15
+    assert sum(k == 5 for *_, k in stacks) == 6
+    assert {(s, k) for *_, s, k in stacks} == {(1, 3), (2, 3), (1, 5), (2, 5)}
+
+
+def test_dwconv_bands_vmapped_compiles_on_mnv3l_plan(one_chip):
+    """At the served bucket of 32 the batch folds into every 3x3 and 5x5
+    band stack of the committed MobileNetV3-Large plan, and the tiling
+    keeps the few-step, in-budget blocks of the MobileNetV2 plans."""
+    batch = MNV3L_PLAN[1]
+    stacks = mnv3l_dwconv_stacks()
+    total = 0
+    for n, c, rows, cols, stride, k in stacks:
+        blk = dwconv_blocks(batch * n, rows, cols, c, stride, k)
+        g = geometry(rows, cols, stride, k)
+        assert step_vmem_bytes(blk.stack, blk.channels, blk.rows,
+                               g) <= VMEM_BUDGET
+        # a narrower channel block pads C no further than 128 lanes do
+        assert -(-c // blk.channels) * blk.channels <= -(-c // 128) * 128
+        steps = batch * n // blk.stack * -(-c // blk.channels)
+        assert steps <= MNV3L_MAX_STEPS["call"]
+        total += steps
+    assert total <= MNV3L_MAX_STEPS["dispatch"]
+    for n, c, rows, cols, stride, k in sorted(set(stacks)):
+        fn = jax.vmap(functools.partial(BANDS[k], stride=stride,
+                                        activation="hswish", out_scale=0.05,
+                                        interpret=False),
+                      in_axes=(0, None, None, None))
+        _compile(fn, _spec(one_chip, (batch, n, c, rows, cols), jnp.int8),
+                 _spec(one_chip, (c, k, k), jnp.int8),
+                 _spec(one_chip, (c,), jnp.float32),
+                 _spec(one_chip, (c,), jnp.int32))
