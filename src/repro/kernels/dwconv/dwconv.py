@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..backend import resolve_interpret
+from ..batching import stack_folding
 from ..epilogue import epilogue
 
 # Estimated VMEM of one grid step (double-buffered blocks plus the row
@@ -225,29 +226,12 @@ def _dwconv_stack(x_win, w, scale, bias, *, stride: int,
     return out[..., :c].transpose(0, 3, 1, 2)
 
 
-def _stack_folding(fn):
-    """``fn`` on a (N, C, R, Wp) stack, with a vmap rule that folds a
-    batched stack (B, N, ...) into one (B*N, ...) stack and calls ``fn``
-    once.  Batched weights (never the executor's case) vmap ``fn`` as is."""
-    folded = jax.custom_batching.custom_vmap(fn)
-
-    @folded.def_vmap
-    def _rule(axis_size, in_batched, x, w, scale, bias):
-        if any(in_batched[1:]) or not in_batched[0]:
-            axes = tuple(0 if b else None for b in in_batched)
-            return jax.vmap(fn, in_axes=axes)(x, w, scale, bias), True
-        y = folded(x.reshape(-1, *x.shape[2:]), w, scale, bias)
-        return y.reshape(axis_size, -1, *y.shape[1:]), True
-
-    return folded
-
-
 def _bands(x_win, w, scale, bias, *, stride, activation, out_scale,
            interpret):
     fn = functools.partial(_dwconv_stack, stride=stride,
                            activation=activation, out_scale=out_scale,
                            interpret=resolve_interpret(interpret))
-    return _stack_folding(fn)(x_win, w, scale, bias)
+    return stack_folding(fn)(x_win, w, scale, bias)
 
 
 _STATIC = ("stride", "activation", "out_scale", "interpret")

@@ -1,5 +1,5 @@
-"""jit'd public wrappers around the qgemm Pallas kernel: padding to block
-multiples, plus the im2col path that lowers the paper's quantized conv +
+"""Public wrappers around the qgemm Pallas kernel: the GEMM on arbitrary
+shapes, plus the im2col path that lowers the paper's quantized conv +
 folded-BN + ReLU6 onto the GEMM kernel."""
 from __future__ import annotations
 
@@ -9,28 +9,13 @@ from .qgemm import qgemm
 from .ref import qgemm_ref
 
 
-def _pad_to(x, m, axis):
-    pad = (-x.shape[axis]) % m
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
-
-
 def qgemm_padded(x_q, w_q, scale, bias, *, activation=None, out_scale=None,
-                 block_m=128, block_n=128, block_k=128, interpret=None):
-    """qgemm on arbitrary shapes (pads to block multiples, slices back)."""
-    m, k = x_q.shape
-    n = w_q.shape[1]
-    xp = _pad_to(_pad_to(x_q, block_m, 0), block_k, 1)
-    wp = _pad_to(_pad_to(w_q, block_k, 0), block_n, 1)
-    sp = _pad_to(scale, block_n, 0)
-    bp = _pad_to(bias, block_n, 0)
-    out = qgemm(xp, wp, sp, bp, activation=activation, out_scale=out_scale,
-                block_m=block_m, block_n=block_n, block_k=block_k,
-                interpret=interpret)
-    return out[:m, :n]
+                 interpret=None):
+    """qgemm on arbitrary shapes, with the blocks the kernel's tiling rule
+    (:func:`.qgemm.qgemm_blocks`) picks for the shape; under ``jax.vmap``
+    the batch folds into M."""
+    return qgemm(x_q, w_q, scale, bias, activation=activation,
+                 out_scale=out_scale, interpret=interpret)
 
 
 def im2col(x_q, kernel_hw, stride, padding):

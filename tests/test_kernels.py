@@ -12,18 +12,25 @@ from repro.kernels.dwconv.dwconv import dwconv3x3_bands, dwconv_blocks
 from repro.kernels.dwconv.ops import dwconv, dwconv_bands, dwconv_ref
 from repro.kernels.dwconv.ref import dwconv_valid_ref
 from repro.kernels.qgemm.ops import (qconv2d, qconv2d_ref, qgemm_padded)
+from repro.kernels.qgemm.qgemm import (VMEM_BUDGET, qgemm, qgemm_blocks,
+                                       step_vmem_bytes)
 from repro.kernels.qgemm.ref import qgemm_ref
 
+from qgemm_calls import PLANS as QGEMM_PLANS
+from qgemm_calls import eqns as _eqns
+from qgemm_calls import qgemm_calls
 
-def _eqns(jaxpr):
-    """Every equation of ``jaxpr`` and of the jaxprs in its parameters."""
-    for e in jaxpr.eqns:
-        yield e
-        for v in e.params.values():
-            for sub in v if isinstance(v, (list, tuple)) else (v,):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
+
+def _gemm_operands(rng, shape, k, n, bias):
+    """int8 operands, and a scale that spreads the outputs over the
+    activation's bends and the int8 range rather than saturating them."""
+    x = rng.integers(-127, 128, shape).astype(np.int8)
+    w = rng.integers(-127, 128, (k, n)).astype(np.int8)
+    s = (rng.uniform(0.5, 1.5, n) * 3 / (5376 * np.sqrt(k))
+         ).astype(np.float32)
+    b = (rng.integers(-5000, 5000, n).astype(np.int32) if bias == "int"
+         else rng.uniform(-1, 1, n).astype(np.float32))
+    return x, w, s, b
 
 
 @pytest.fixture
@@ -59,6 +66,101 @@ class TestQGEMM:
         got = np.asarray(qgemm_padded(x, w, ones, zeros))
         exp = x.astype(np.int64) @ w.astype(np.int64)
         np.testing.assert_array_equal(got.astype(np.int64), exp)
+
+    # (batch, M, K, N, bias): shapes of the plans' GEMMs (M of one sample:
+    # 1 for a linear, 49 a 7x7 map, 3136 a 56x56 one, 19712 MobileNetV3's
+    # banded stem); the largest M only at batch 1, to keep interpret mode fast
+    @pytest.mark.parametrize("batch,m,k,n,bias", [
+        (1, 19712, 27, 16, "int"),
+        (1, 1, 1280, 1280, "float"),
+        (8, 49, 1280, 16, "int"),
+        (8, 3136, 96, 144, "float"),
+        (8, 1, 96, 1280, "int"),
+        (32, 1, 1280, 144, "int"),
+        (32, 49, 96, 1280, "float"),
+        (32, 3136, 27, 16, "int"),
+        (32, 49, 27, 144, "float"),
+    ])
+    def test_qgemm_vmapped_bit_exact(self, rng, batch, m, k, n, bias):
+        """The batch folded into M equals every sample through the oracle:
+        bit for bit with the int32 bias; within one output step with a
+        float bias, whose ``acc * scale + bias`` may contract to an FMA on
+        one side only (as in ``test_sweep_vs_ref``)."""
+        x, w, s, b = _gemm_operands(rng, (batch, m, k), k, n, bias)
+        fn = jax.vmap(functools.partial(qgemm_padded, activation="hswish",
+                                        out_scale=0.05),
+                      in_axes=(0, None, None, None))
+        got = np.asarray(fn(x, w, s, b))
+        exp = np.asarray(qgemm_ref(x.reshape(-1, k), w, s, b,
+                                   activation="hswish", out_scale=0.05)
+                         ).reshape(batch, m, n)
+        assert got.shape == exp.shape and got.dtype == np.int8
+        if bias == "int":
+            np.testing.assert_array_equal(got, exp)
+        else:
+            assert np.max(np.abs(got.astype(np.int32) - exp)) <= 1
+
+    @pytest.mark.parametrize("batch,m,k,n", [
+        (32, 19712, 27, 16), (32, 1, 1280, 1000), (8, 49, 96, 144),
+        (1, 3136, 24, 144)])
+    def test_qgemm_vmap_folds_into_one_call(self, batch, m, k, n):
+        """Under vmap the GEMM is one pallas_call over all B*M rows, with
+        the grid of the blocks ``qgemm_blocks`` picks and no batch axis."""
+        fn = jax.vmap(functools.partial(qgemm_padded, out_scale=0.05),
+                      in_axes=(0, None, None, None))
+        jaxpr = jax.make_jaxpr(fn)(
+            jnp.zeros((batch, m, k), jnp.int8), jnp.zeros((k, n), jnp.int8),
+            jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.int32))
+        grids = [e.params["grid_mapping"].grid
+                 for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+        blk = qgemm_blocks(batch * m, k, n)
+        assert grids == [(-(-batch * m // blk.m), -(-n // 128) * 128 // blk.n,
+                          -(-k // 128) * 128 // blk.k)]
+
+    def test_qgemm_vmap_over_weights(self, rng):
+        """Batched weights (the outer vmap, over x and w) fall back to
+        vmapping the call; the inner vmap, over x alone, still folds."""
+        x, w, s, b = _gemm_operands(rng, (2, 3, 40, 50), 50, 24, "int")
+        ws = rng.integers(-127, 128, (2, 50, 24)).astype(np.int8)
+        call = functools.partial(qgemm_padded, activation="relu6",
+                                 out_scale=0.05)
+        nested = jax.vmap(jax.vmap(call, in_axes=(0, None, None, None)),
+                          in_axes=(0, 0, None, None))
+        got = np.asarray(nested(x, ws, s, b))
+        for i in range(2):
+            exp = qgemm_ref(x[i].reshape(-1, 50), ws[i], s, b,
+                            activation="relu6", out_scale=0.05)
+            np.testing.assert_array_equal(
+                got[i], np.asarray(exp).reshape(3, 40, 24))
+
+    def test_k_split_over_the_grid(self, rng):
+        """Explicit blocks narrower than K split it over the grid's last
+        axis, the int32 partial sums kept across steps; M not a multiple of
+        the block leaves an overhanging last block, and a block of rows not
+        a multiple of the in-kernel chunk leaves a shorter last chunk."""
+        x, w, s, b = _gemm_operands(rng, (700, 300), 300, 130, "int")
+        got = qgemm(x, w, s, b, activation="hswish", out_scale=0.05,
+                    block_m=640, block_n=256, block_k=128)
+        exp = qgemm_ref(x, w, s, b, activation="hswish", out_scale=0.05)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+
+    @pytest.mark.parametrize("plan", sorted(QGEMM_PLANS))
+    def test_qgemm_blocks_within_budget(self, plan):
+        """Every GEMM of the committed plans, at buckets 1 and 32: whole
+        blocks of int8 row tiles over the padded shape, a VMEM estimate
+        within the budget, and the kernel's grid is those blocks'."""
+        for batch in (1, 32):
+            calls = qgemm_calls(plan, batch)
+            assert calls
+            for c in calls:
+                blk = qgemm_blocks(c.m, c.k, c.n)
+                kp, np_ = -(-c.k // 128) * 128, -(-c.n // 128) * 128
+                assert blk.m % 32 == 0 and kp % blk.k == 0 and np_ % blk.n == 0
+                assert step_vmem_bytes(blk.m, blk.k, blk.n,
+                                       kp // blk.k) <= VMEM_BUDGET
+                assert c.grid == (-(-c.m // blk.m), np_ // blk.n, kp // blk.k)
+                # M pads by less than one int8 row tile a block
+                assert c.grid[0] * blk.m - c.m < 32 * c.grid[0]
 
     @pytest.mark.parametrize("stride", [(1, 1), (2, 2)])
     def test_qconv2d(self, rng, stride):

@@ -9,13 +9,17 @@ MobileNetV2@112 shape the served path uses, every depthwise band stack of
 the committed MobileNetV3-Large@224 plan (3x3 and 5x5), plus one whole int8
 ``run_batch`` program, and check that each lowers to a compiled kernel
 (``tpu_custom_call``).  Nothing runs, so results are checked elsewhere.
+Beside them, with no chip described, the tiling rules are checked on the
+committed plans: the depthwise blocks, and the GEMM's grid steps a dispatch.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time.
 """
 import functools
+import math
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +33,8 @@ from repro.kernels.dwconv.dwconv import (BANDS, VMEM_BUDGET, dwconv3x3,
                                          geometry, step_vmem_bytes)
 from repro.kernels.qgemm.ops import qgemm_padded
 from repro.models import mobilenet_v2_paper, mobilenet_v3_large
+
+from qgemm_calls import qgemm_calls
 
 # (channels, input rows = cols, stride) of every MobileNetV2@112 depthwise
 # layer; test_depthwise_shapes_match_model keeps the list honest
@@ -50,6 +56,15 @@ MNV3L_PLAN = ("mnv3l_224_int8_spatial.plan.json", 32)
 # at 224x224 one band of the 112x112 maps fills a step's VMEM budget alone,
 # so a call at bucket 32 takes up to 32 x 7 steps
 MNV3L_MAX_STEPS = {"call": 256, "dispatch": 768}
+# qgemm grid steps a dispatch with 128x128x128 blocks and the vmap batch as
+# a grid axis, by committed plan and bucket; the tiling must take a tenth of
+# them at buckets 8 and 32 and no more at bucket 1
+QGEMM_STEPS_128 = {("mnv3l", 1): 1471, ("mnv3l", 32): 47072,
+                   ("spatial", 1): 531, ("spatial", 8): 4248,
+                   ("spatial", 32): 16992,
+                   ("neuron", 1): 1136, ("neuron", 32): 36352}
+# the pattern of the benchmark's qgemm_roofline metrics (bench/metrics)
+QGEMM_EVENT = re.compile(r"^\s*%?[\w.]*jit_qgemm[\w.]* = ", re.M)
 
 
 @pytest.fixture(scope="module")
@@ -202,16 +217,31 @@ def test_dwconv_tiling_on_committed_plans(model, plan):
     (8, 3136, 24, 144),      # 56x56 expand conv: N pads to 256
     (2, 16, 960, 320),       # 4x4 project conv
     (2, 1, 1280, 1000),      # classifier
+    (32, 19712, 27, 16),     # MobileNetV3-Large's banded stem at bucket 32
+    (32, 1, 1280, 1000),     # classifier at bucket 32: one 32-row GEMM
 ])
 def test_qgemm_padded_vmapped_compiles(one_chip, batch, m, k, n):
-    """``run_batch`` vmaps the plan, so the GEMM kernel compiles batched."""
+    """``run_batch`` vmaps the plan, so the GEMM kernel compiles batched,
+    the batch folded into M; its op keeps the name the benchmark's
+    ``qgemm_roofline`` pattern reads."""
     fn = jax.vmap(functools.partial(qgemm_padded, activation="relu6",
                                     out_scale=0.05, interpret=False),
                   in_axes=(0, None, None, None))
-    _compile(fn, _spec(one_chip, (batch, m, k), jnp.int8),
-             _spec(one_chip, (k, n), jnp.int8),
-             _spec(one_chip, (n,), jnp.float32),
-             _spec(one_chip, (n,), jnp.int32))
+    compiled = _compile(fn, _spec(one_chip, (batch, m, k), jnp.int8),
+                        _spec(one_chip, (k, n), jnp.int8),
+                        _spec(one_chip, (n,), jnp.float32),
+                        _spec(one_chip, (n,), jnp.int32))
+    assert QGEMM_EVENT.search(compiled.as_text())
+
+
+@pytest.mark.parametrize("plan,batch", sorted(QGEMM_STEPS_128))
+def test_qgemm_tiling_on_committed_plans(plan, batch):
+    """The qgemm grid steps a dispatch of the committed plans: at most a
+    tenth of the 128x128x128 blocks' at bucket 8 and 32, and no more at
+    bucket 1 (steady's partial buckets)."""
+    steps = sum(math.prod(c.grid) for c in qgemm_calls(plan, batch))
+    limit = QGEMM_STEPS_128[plan, batch]
+    assert steps <= (limit if batch == 1 else limit // 10)
 
 
 def test_int8_run_batch_compiles(one_chip, model):
